@@ -2,8 +2,8 @@
 relative scaling only — Pallas kernels target TPU and are validated in
 interpret mode) plus end-to-end fixpoint benchmarks per kernel backend:
 the same Datalog programs run under ``kernel_backend="jnp"`` and
-``"pallas"`` so the dispatch layer's effect is measured through the
-whole semi-naive loop, not per kernel. On CPU the pallas rows time
+``"pallas-interpret"`` so the dispatch layer's effect is measured
+through the whole semi-naive loop, not per kernel. The pallas rows time
 interpret mode — a correctness/lowering proxy, not the TPU speedup."""
 from __future__ import annotations
 
@@ -80,7 +80,7 @@ def bench_fixpoint_backends(repeats: int = 3) -> list[dict]:
     rows = []
     for pname, (src, edbs) in progs.items():
         compiled = compile_program(src)
-        for backend in ("jnp", "pallas"):
+        for backend in ("jnp", "pallas-interpret"):
             eng = Engine(compiled, EngineConfig(
                 idb_cap=1 << 13, intermediate_cap=1 << 15,
                 kernel_backend=backend))

@@ -90,6 +90,8 @@ def main() -> None:
                          "--smoke defaults to results/bench-smoke.json "
                          "so tiny rows never clobber real results)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = args.only.split(",") if args.only else None
     if args.out is None:
         args.out = ("results/bench-smoke.json" if args.smoke

@@ -22,8 +22,9 @@ Two questions, one table:
 
 * **Wide fixpoints per backend.** The newly supported 4-6 column
   programs end-to-end under both kernel backends. On CPU these
-  end-to-end times are compile-dominated (each run re-jits) and pallas
-  = interpret mode — a correctness/lowering proxy, not a TPU speedup;
+  end-to-end times are compile-dominated (each run re-jits) and the
+  pallas rows run in interpret mode — a correctness/lowering proxy, not
+  a TPU speedup;
   the check that matters is identical facts + iterations per pair.
 """
 from __future__ import annotations
@@ -141,7 +142,7 @@ def bench() -> list[dict]:
     for name in WIDE_PROGRAMS:
         src, edbs = datasets[name]
         per_backend = {}
-        for backend in ("jnp", "pallas"):
+        for backend in ("jnp", "pallas-interpret"):
             res = {}
             t = _best(lambda: res.update(
                 zip(("out", "stats"), run(src, edbs, backend))))
@@ -154,7 +155,7 @@ def bench() -> list[dict]:
                 "iterations": stats.total_iterations,
             })
         (_, oj, sj), (_, op_, sp) = (per_backend["jnp"],
-                                     per_backend["pallas"])
+                                     per_backend["pallas-interpret"])
         assert all(np.array_equal(oj[k], op_[k]) for k in oj)
         assert sj.iterations == sp.iterations
     return rows

@@ -55,17 +55,18 @@ provided:
     plain XLA ops.
   * ``PallasDispatch`` — the TPU Pallas kernels in ``repro.kernels``
     (``merge_probe_counts`` blocked merge-path probe,
-    ``segment_reduce`` one-hot-matmul segment reduction), run in
-    interpret mode when no TPU is attached so CPU CI validates the
-    exact kernel bodies that deploy.
+    ``segment_reduce`` one-hot segment reduction), compiled for the TPU,
+    or run in interpret mode on request so CPU CI validates the exact
+    kernel bodies that deploy.
 
 Selection happens ONCE at engine construction from
 ``EngineConfig.kernel_backend``:
 
-  "auto"   -> "pallas" on TPU, "jnp" otherwise (interpret mode is a
-              validation tool, not a fast CPU path)
-  "pallas" -> compiled kernels on TPU, interpret mode elsewhere
-  "jnp"    -> pure-jnp everywhere
+  "auto"             -> "pallas" on TPU, "jnp" otherwise
+  "pallas"           -> compiled kernels; raises when JAX has no TPU
+  "pallas-interpret" -> the same kernel bodies in interpret mode (a
+                        validation tool, not a fast CPU path)
+  "jnp"              -> pure-jnp everywhere
 
 Contracts the dispatch boundary guarantees (and the equivalence tests
 in tests/test_backend_equivalence.py pin down):
@@ -222,8 +223,9 @@ class JnpDispatch(KernelDispatch):
 
 
 class PallasDispatch(KernelDispatch):
-    """Routes to the Pallas kernels (compiled on TPU, interpret mode on
-    CPU so tests exercise the deployed kernel bodies)."""
+    """Routes to the Pallas kernels: compiled for the TPU, or run in
+    interpret mode (``interpret=True``) so CPU tests exercise the
+    deployed kernel bodies."""
 
     needs_sorted_probe = True
 
@@ -282,7 +284,12 @@ def resolve_backend(spec: "str | KernelDispatch | None" = "auto",
     if spec == "jnp":
         return JNP
     if spec == "pallas":
-        return PallasDispatch(interpret=not on_tpu)
+        if not on_tpu:
+            raise RuntimeError(
+                "kernel_backend='pallas' compiles the kernels for a TPU, "
+                f"but JAX's default backend is {jax.default_backend()!r}; "
+                "use 'pallas-interpret' to run them in interpret mode")
+        return PallasDispatch(interpret=False)
     if spec == "pallas-interpret":
         return PallasDispatch(interpret=True)
     raise ValueError(

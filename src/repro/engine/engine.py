@@ -53,7 +53,8 @@ class EngineConfig:
     semiring: Semiring = PRESENCE  # execution algebra (Sec. 8)
     jit: bool = True
     # physical backend for probe/reduce hot ops (engine/backend.py):
-    # "auto" (Pallas on TPU, jnp elsewhere) | "pallas" | "jnp";
+    # "auto" (Pallas on TPU, jnp elsewhere) | "pallas" (TPU only) |
+    # "pallas-interpret" | "jnp";
     # a KernelDispatch instance is also accepted. Resolved once at
     # engine construction.
     kernel_backend: str = "auto"

@@ -72,7 +72,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.core import ir as I
@@ -322,8 +321,8 @@ class ShardedEngine(Engine):
 
     # -- shard_map plumbing ---------------------------------------------------
     def _shmap(self, f, in_specs=_SPEC, out_specs=_SPEC, jit=True):
-        g = shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+        g = jax.shard_map(f, mesh=self.mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
         return jax.jit(g) if (jit and self.cfg.jit) else g
 
     def _scatter_env(self, rels: dict) -> dict:

@@ -2,7 +2,9 @@
 
 Each kernel ships three layers:
   <name>.py — pl.pallas_call body + BlockSpec VMEM tiling (TPU target,
-              validated with interpret=True on CPU)
+              validated with interpret=True on CPU; the engine kernels
+              are compiled for a described v5e by
+              tests/test_tpu_compile.py)
   ops.py    — jit'd public wrappers with shape plumbing + fallback
   ref.py    — pure-jnp oracles the tests assert against
 
@@ -25,7 +27,8 @@ The engine backend seam
 The Datalog engine consumes ``segment_reduce`` and
 ``merge_probe_counts`` through the kernel-dispatch layer in
 ``repro.engine.backend`` (selected by ``EngineConfig.kernel_backend``:
-"auto" | "pallas" | "jnp"), so these two kernels ARE the engine's
+"auto" | "pallas" | "pallas-interpret" | "jnp"), so these two kernels
+ARE the engine's
 physical execution backend on TPU rather than standalone demos:
 
   merge_probe_counts — the count/locate phase of ``relops.join``

@@ -24,14 +24,25 @@ packs reach bit 62; KEY_PAD is 2**63 - 1) are split into an int32 pair
 matches unsigned chunk order) and compared lexicographically in-kernel
 with plain signed compares.
 
-``merge_probe_multi_pallas`` generalizes the same kernel to the
-engine's multi-word lexicographic keys (relation.pack_key_words): a key
-of W int64 words becomes 2W int32 chunks, and the in-kernel compare
-folds over the chunk axis (a static Python loop, unrolled at trace
-time) — block skip logic and rank accumulation are unchanged. W = 1
-reduces to exactly the single-word kernel's compare, and the engine
-keeps routing narrow keys through ``merge_probe_pallas`` so the fast
-path is bit- and schedule-identical to before.
+``merge_probe_multi_pallas`` runs the same kernel on the engine's
+multi-word lexicographic keys (relation.pack_key_words): a key of W
+int64 words becomes 2W int32 chunks, and the in-kernel compare folds
+over the chunks (a static Python loop, unrolled at trace time). A
+single-word key is the W = 1 case: two chunks. The engine keeps routing
+narrow keys through ``merge_probe_pallas``, whose block bounds come
+from a plain searchsorted on the int64 keys.
+
+TPU layout. The skip decision needs each block's min/max, which are
+scalars: the kernel reads them from SMEM. Both sides are sorted, so the
+build blocks wholly below probe block ``p`` are a prefix ``[0, r_lo[p])``
+and those wholly above it a suffix ``[r_hi[p], nb)``. The wrapper
+computes the two bounds per probe block from the block min/max keys
+(a searchsorted over the ``nb`` block ends) and scalar-prefetches them
+(``pltpu.PrefetchScalarGridSpec``): two int32 words per probe block, so
+SMEM (1 MiB on v5e) holds the bounds of up to 2**26 probe rows. Key
+chunks travel as 1-D int32 blocks whose length is a multiple of 1024
+(XLA's tiling of a 1-D int32 array on TPU), and every in-kernel
+reduction is int32 (the package turns x64 on).
 """
 from __future__ import annotations
 
@@ -40,19 +51,33 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import ref
 
 
-def _lex_lt(ah, al, bh, bl):
-    return (ah < bh) | ((ah == bh) & (al < bl))
+def _chunk_lex_lt_le(a_chunks, b_chunks):
+    """Fold a lexicographic (lt, le) compare over a static sequence of
+    int32 chunk arrays (broadcastable shapes)."""
+    lt = None
+    eq = None
+    for a, b in zip(a_chunks, b_chunks):
+        if lt is None:
+            lt = a < b
+            eq = a == b
+        else:
+            lt = lt | (eq & (a < b))
+            eq = eq & (a == b)
+    return lt, lt | eq
 
 
-def _lex_le(ah, al, bh, bl):
-    return (ah < bh) | ((ah == bh) & (al <= bl))
-
-
-def _probe_kernel(bmin_h_ref, bmin_l_ref, bmax_h_ref, bmax_l_ref,
-                  ph_ref, pl_ref, bh_ref, bl_ref,
-                  lo_ref, hi_ref, *, build_block: int):
+def _probe_kernel(rlo_ref, rhi_ref, *refs, build_block: int, nchunks: int):
+    """One (probe block, build block) step. ``refs`` holds the probe
+    block's ``nchunks`` int32 key chunks, the build block's, then the
+    (lo, hi) output blocks."""
+    p_refs, b_refs = refs[:nchunks], refs[nchunks:2 * nchunks]
+    lo_ref, hi_ref = refs[2 * nchunks:]
+    p = pl.program_id(0)
     r = pl.program_id(1)
 
     @pl.when(r == 0)
@@ -60,14 +85,8 @@ def _probe_kernel(bmin_h_ref, bmin_l_ref, bmax_h_ref, bmax_l_ref,
         lo_ref[...] = jnp.zeros_like(lo_ref)
         hi_ref[...] = jnp.zeros_like(hi_ref)
 
-    ph, pll = ph_ref[...], pl_ref[...]          # [probe_block]
-    pmax_h, pmax_l = ph[-1], pll[-1]            # probes sorted
-    pmin_h, pmin_l = ph[0], pll[0]
-    bmin_h, bmin_l = bmin_h_ref[0], bmin_l_ref[0]
-    bmax_h, bmax_l = bmax_h_ref[0], bmax_l_ref[0]
-
-    below_all = _lex_lt(bmax_h, bmax_l, pmin_h, pmin_l)
-    above_all = _lex_lt(pmax_h, pmax_l, bmin_h, bmin_l)
+    below_all = r < rlo_ref[p]
+    above_all = r >= rhi_ref[p]
 
     @pl.when(below_all)
     def _full():
@@ -75,13 +94,53 @@ def _probe_kernel(bmin_h_ref, bmin_l_ref, bmax_h_ref, bmax_l_ref,
         lo_ref[...] += build_block
         hi_ref[...] += build_block
 
-    @pl.when(~below_all & ~above_all)
+    @pl.when(jnp.logical_not(below_all | above_all))
     def _compare():
-        bh, bl = bh_ref[...], bl_ref[...]       # [build_block]
-        lt = _lex_lt(bh[None, :], bl[None, :], ph[:, None], pll[:, None])
-        le = _lex_le(bh[None, :], bl[None, :], ph[:, None], pll[:, None])
-        lo_ref[...] += lt.sum(axis=1).astype(jnp.int32)
-        hi_ref[...] += le.sum(axis=1).astype(jnp.int32)
+        lt, le = _chunk_lex_lt_le(
+            [b[...][None, :] for b in b_refs],   # [1, build_block]
+            [q[...][:, None] for q in p_refs])   # [probe_block, 1]
+        lo_ref[...] += _count_true(lt)
+        hi_ref[...] += _count_true(le)
+
+
+def _count_true(mask):
+    """Row-wise count of a [probe_block, build_block] mask, in int32
+    (a bool sum would promote to int64 under x64)."""
+    return mask.astype(jnp.int32).sum(axis=1, dtype=jnp.int32)
+
+
+def _block_bounds(build_min, build_max, probe_min, probe_max, ranks):
+    """Per probe block, the build-block range ``[r_lo, r_hi)`` that needs
+    compares: ``r_lo`` = #build blocks whose max is below the probe
+    block's min, ``r_hi`` = #build blocks whose min is at or below its
+    max. ``ranks(build, probe) -> (lo, hi)`` is searchsorted left/right
+    over the sorted block keys."""
+    r_lo, _ = ranks(build_max, probe_min)
+    _, r_hi = ranks(build_min, probe_max)
+    return r_lo.astype(jnp.int32), r_hi.astype(jnp.int32)
+
+
+def _probe_call(probe_chunks, build_chunks, bounds, probe_block,
+                build_block, interpret):
+    """The block-pair grid shared by both probe entry points:
+    scalar-prefetched build-block bounds, then the probe and build key
+    chunks as 1-D int32 arrays."""
+    n_pad = probe_chunks[0].shape[0]
+    nb = build_chunks[0].shape[0] // build_block
+    nchunks = len(probe_chunks)
+    probe_spec = pl.BlockSpec((probe_block,), lambda p, r, *_: (p,))
+    build_spec = pl.BlockSpec((build_block,), lambda p, r, *_: (r,))
+    return pl.pallas_call(
+        functools.partial(_probe_kernel, build_block=build_block,
+                          nchunks=nchunks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_pad // probe_block, nb),
+            in_specs=[probe_spec] * nchunks + [build_spec] * nchunks,
+            out_specs=[probe_spec, probe_spec]),
+        out_shape=[jax.ShapeDtypeStruct((n_pad,), jnp.int32)] * 2,
+        interpret=interpret,
+    )(*bounds, *probe_chunks, *build_chunks)
 
 
 @functools.partial(
@@ -90,7 +149,7 @@ def _probe_kernel(bmin_h_ref, bmin_l_ref, bmax_h_ref, bmax_l_ref,
 def merge_probe_pallas(
     build_keys: jax.Array,    # [m] int64 sorted ascending (pad: int64 max)
     probe_keys: jax.Array,    # [n] int64 sorted ascending
-    probe_block: int = 512,
+    probe_block: int = 1024,
     build_block: int = 1024,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
@@ -109,39 +168,17 @@ def merge_probe_pallas(
 
     m_pad = pl.cdiv(max(m, 1), build_block) * build_block
     n_pad = pl.cdiv(max(n, 1), probe_block) * probe_block
-    build_keys = jnp.pad(build_keys, (0, m_pad - m), constant_values=MAXK)
-    probe_keys = jnp.pad(probe_keys, (0, n_pad - n), constant_values=MAXK)
-    bh, bl = split(build_keys)
-    ph, pll = split(probe_keys)
+    build_keys = jnp.pad(build_keys.astype(jnp.int64), (0, m_pad - m),
+                         constant_values=MAXK)
+    probe_keys = jnp.pad(probe_keys.astype(jnp.int64), (0, n_pad - n),
+                         constant_values=MAXK)
     nb = m_pad // build_block
-    bmin_h = bh.reshape(nb, build_block)[:, 0]
-    bmin_l = bl.reshape(nb, build_block)[:, 0]
-    bmax_h = bh.reshape(nb, build_block)[:, -1]
-    bmax_l = bl.reshape(nb, build_block)[:, -1]
-
-    lo, hi = pl.pallas_call(
-        functools.partial(_probe_kernel, build_block=build_block),
-        grid=(n_pad // probe_block, nb),
-        in_specs=[
-            pl.BlockSpec((1,), lambda p, r: (r,)),
-            pl.BlockSpec((1,), lambda p, r: (r,)),
-            pl.BlockSpec((1,), lambda p, r: (r,)),
-            pl.BlockSpec((1,), lambda p, r: (r,)),
-            pl.BlockSpec((probe_block,), lambda p, r: (p,)),
-            pl.BlockSpec((probe_block,), lambda p, r: (p,)),
-            pl.BlockSpec((build_block,), lambda p, r: (r,)),
-            pl.BlockSpec((build_block,), lambda p, r: (r,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((probe_block,), lambda p, r: (p,)),
-            pl.BlockSpec((probe_block,), lambda p, r: (p,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(bmin_h, bmin_l, bmax_h, bmax_l, ph, pll, bh, bl)
+    bblk = build_keys.reshape(nb, build_block)
+    pblk = probe_keys.reshape(n_pad // probe_block, probe_block)
+    bounds = _block_bounds(bblk[:, 0], bblk[:, -1], pblk[:, 0],
+                           pblk[:, -1], ref.merge_probe_ref)
+    lo, hi = _probe_call(split(probe_keys), split(build_keys), bounds,
+                         probe_block, build_block, interpret)
     # padded build rows carry MAXK; probes that are real never count them
     # as < or <= unless the probe itself is MAXK (a padded probe) —
     # those rows are sliced off here.
@@ -151,7 +188,7 @@ def merge_probe_pallas(
 # -- merge ranks (incremental arrangement maintenance) -----------------------
 
 def merge_ranks_pallas(a_keys: jax.Array, b_keys: jax.Array,
-                       probe_block: int = 512, build_block: int = 1024,
+                       probe_block: int = 1024, build_block: int = 1024,
                        interpret: bool = False):
     """Merge-path output positions for a stable two-pointer merge of two
     sorted key sequences (``a`` wins ties) — the Pallas counterpart of
@@ -181,7 +218,7 @@ def merge_ranks_pallas(a_keys: jax.Array, b_keys: jax.Array,
 
 
 def merge_ranks_multi_pallas(a_words: jax.Array, b_words: jax.Array,
-                             probe_block: int = 512,
+                             probe_block: int = 1024,
                              build_block: int = 1024,
                              interpret: bool = False):
     """Multi-word ``merge_ranks_pallas``: [m, W] / [n, W] int64 key
@@ -202,63 +239,13 @@ def merge_ranks_multi_pallas(a_words: jax.Array, b_words: jax.Array,
 
 # -- multi-word keys ---------------------------------------------------------
 
-def _chunk_lex_lt_le(a_chunks, b_chunks):
-    """Fold a lexicographic (lt, le) compare over a static sequence of
-    int32 chunk arrays (broadcastable shapes)."""
-    lt = None
-    eq = None
-    for a, b in zip(a_chunks, b_chunks):
-        if lt is None:
-            lt = a < b
-            eq = a == b
-        else:
-            lt = lt | (eq & (a < b))
-            eq = eq & (a == b)
-    return lt, lt | eq
-
-
-def _probe_multi_kernel(bmin_ref, bmax_ref, pc_ref, bc_ref,
-                        lo_ref, hi_ref, *, build_block: int,
-                        nchunks: int):
-    r = pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _init():
-        lo_ref[...] = jnp.zeros_like(lo_ref)
-        hi_ref[...] = jnp.zeros_like(hi_ref)
-
-    pc = pc_ref[...]                            # [nchunks, probe_block]
-    pmin = [pc[c, 0] for c in range(nchunks)]   # probes sorted
-    pmax = [pc[c, -1] for c in range(nchunks)]
-    bmin = [bmin_ref[c, 0] for c in range(nchunks)]
-    bmax = [bmax_ref[c, 0] for c in range(nchunks)]
-
-    below_all, _ = _chunk_lex_lt_le(bmax, pmin)
-    above_all, _ = _chunk_lex_lt_le(pmax, bmin)
-
-    @pl.when(below_all)
-    def _full():
-        # entire build block strictly below every probe key
-        lo_ref[...] += build_block
-        hi_ref[...] += build_block
-
-    @pl.when(~below_all & ~above_all)
-    def _compare():
-        bc = bc_ref[...]                        # [nchunks, build_block]
-        lt, le = _chunk_lex_lt_le(
-            [bc[c][None, :] for c in range(nchunks)],
-            [pc[c][:, None] for c in range(nchunks)])
-        lo_ref[...] += lt.sum(axis=1).astype(jnp.int32)
-        hi_ref[...] += le.sum(axis=1).astype(jnp.int32)
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("probe_block", "build_block", "interpret"))
 def merge_probe_multi_pallas(
     build_words: jax.Array,   # [m, W] int64, lexicographically ascending
     probe_words: jax.Array,   # [n, W] int64, lexicographically ascending
-    probe_block: int = 512,
+    probe_block: int = 1024,
     build_block: int = 1024,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
@@ -271,48 +258,27 @@ def merge_probe_multi_pallas(
     MAXK = jnp.iinfo(jnp.int64).max
     nchunks = 2 * w
 
-    def split(words):             # [k, W] int64 -> [2W, k] int32 chunks
-        words = words.astype(jnp.int64)
+    def split(words):             # [k, W] int64 -> 2W int32 [k] chunks
         hi = (words >> 32).astype(jnp.int32)
         lo = ((words & 0xFFFFFFFF) - (1 << 31)).astype(jnp.int32)
         # chunk order word0_hi, word0_lo, word1_hi, ... keeps the
         # chunk-wise lex order isomorphic to the word-wise lex order
-        return jnp.stack(
-            [hi[:, c // 2] if c % 2 == 0 else lo[:, c // 2]
-             for c in range(nchunks)], axis=0)
+        return [hi[:, c // 2] if c % 2 == 0 else lo[:, c // 2]
+                for c in range(nchunks)]
 
     m_pad = pl.cdiv(max(m, 1), build_block) * build_block
     n_pad = pl.cdiv(max(n, 1), probe_block) * probe_block
-    build_words = jnp.pad(build_words, ((0, m_pad - m), (0, 0)),
-                          constant_values=MAXK)
-    probe_words = jnp.pad(probe_words, ((0, n_pad - n), (0, 0)),
-                          constant_values=MAXK)
-    bc = split(build_words)                     # [2W, m_pad]
-    pc = split(probe_words)                     # [2W, n_pad]
+    build_words = jnp.pad(build_words.astype(jnp.int64),
+                          ((0, m_pad - m), (0, 0)), constant_values=MAXK)
+    probe_words = jnp.pad(probe_words.astype(jnp.int64),
+                          ((0, n_pad - n), (0, 0)), constant_values=MAXK)
     nb = m_pad // build_block
-    bmin = bc.reshape(nchunks, nb, build_block)[:, :, 0]    # [2W, nb]
-    bmax = bc.reshape(nchunks, nb, build_block)[:, :, -1]
-
-    lo, hi = pl.pallas_call(
-        functools.partial(_probe_multi_kernel, build_block=build_block,
-                          nchunks=nchunks),
-        grid=(n_pad // probe_block, nb),
-        in_specs=[
-            pl.BlockSpec((nchunks, 1), lambda p, r: (0, r)),
-            pl.BlockSpec((nchunks, 1), lambda p, r: (0, r)),
-            pl.BlockSpec((nchunks, probe_block), lambda p, r: (0, p)),
-            pl.BlockSpec((nchunks, build_block), lambda p, r: (0, r)),
-        ],
-        out_specs=[
-            pl.BlockSpec((probe_block,), lambda p, r: (p,)),
-            pl.BlockSpec((probe_block,), lambda p, r: (p,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(bmin, bmax, pc, bc)
+    bblk = build_words.reshape(nb, build_block, w)
+    pblk = probe_words.reshape(n_pad // probe_block, probe_block, w)
+    bounds = _block_bounds(bblk[:, 0], bblk[:, -1], pblk[:, 0],
+                           pblk[:, -1], ref.merge_probe_multi_ref)
+    lo, hi = _probe_call(split(probe_words), split(build_words), bounds,
+                         probe_block, build_block, interpret)
     # padded build rows carry MAXK in every word; real probes never
     # count them. Padded probes are sliced off here (their hi may count
     # block padding — same dead-probe contract as the 1-D kernel).

@@ -2,11 +2,10 @@
 
 Each op takes ``backend=``:
   "pallas"     — compiled Pallas kernel (TPU deployment path)
-  "interpret"  — Pallas kernel body interpreted on CPU (how this
-                 container validates the kernels)
+  "interpret"  — Pallas kernel body interpreted on CPU (how CPU tests
+                 validate the kernels)
   "xla"        — the pure-jnp reference (also the dry-run lowering path,
-                 so cost_analysis reflects XLA collectives/fusions; see
-                 DESIGN.md §5)
+                 so cost_analysis reflects XLA collectives/fusions)
 """
 from __future__ import annotations
 

@@ -10,19 +10,26 @@ we require ``seg_ids`` sorted ascending — which the engine guarantees
 by pre-sorting edges by destination. Two strategies:
 
 * ``resident`` (num_segments small enough for VMEM): grid walks row
-  blocks sequentially; each block one-hot-matmuls its rows into the
-  full segment axis kept resident in VMEM (MXU-friendly
-  [segs, rows] x [rows, d] product). Output revisiting across the
-  sequential grid accumulates boundary segments for free.
+  blocks sequentially; each block folds its rows into the full segment
+  axis kept resident in VMEM. Output revisiting across the sequential
+  grid accumulates boundary segments for free.
 * ``tiled`` (large num_segments): 2-D grid (segment tiles x row blocks);
   each step accumulates the overlap of its segment tile with its row
   block. Sortedness makes most (tile, block) pairs disjoint: a
-  host-precomputed per-row-block [min_seg, max_seg] range lets the
-  kernel skip non-overlapping steps with ``pl.when`` (compute-skip; the
-  grid itself is static, as TPU requires).
+  precomputed per-row-block [min_seg, max_seg] range, scalar-prefetched
+  into SMEM, lets the kernel skip non-overlapping steps with
+  ``pl.when`` (compute-skip; the grid itself is static, as TPU
+  requires).
 
-VMEM budget: rows_block*d (values) + seg_tile*d (out tile) + the
-rows_block*seg_tile one-hot; defaults stay < ~2.5 MB at d=128.
+Both fold a row block into a segment range the same way: a
+[rows_block, chunk] one-hot compare of segment ids against a lane iota,
+a select of the values (or the op's identity), and a reduction over the
+rows, one chunk of ``_SEG_CHUNK`` segments at a time so the temporaries
+stay near 2 MiB of VMEM. Sums run on the VPU in the accumulator dtype:
+int32 sums are exact and wrap like ``jax.ops.segment_sum`` (Mosaic has
+no int32 matmul). Values travel as 1-D blocks whose length is a multiple
+of 1024 (XLA's tiling of a 1-D 32-bit array on TPU); ``d > 1`` columns
+are vmapped over the 1-D kernel (the engine passes one column).
 """
 from __future__ import annotations
 
@@ -31,8 +38,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 RESIDENT_MAX_SEGMENTS = 8192
+# segments compared per step of the row-block fold (VMEM temporaries
+# of rows_block x _SEG_CHUNK)
+_SEG_CHUNK = 512
 
 
 def _neutral(op: str, dtype):
@@ -47,6 +58,28 @@ def _neutral(op: str, dtype):
     return jnp.asarray(jnp.inf if op == "min" else -jnp.inf, dtype)
 
 
+def _fold_rows(out_ref, seg, vals, base, op: str):
+    """Fold one row block (segment ids ``seg``, values ``vals``, both
+    [rows_block]) into ``out_ref`` [width], whose entry ``i`` is segment
+    ``base + i``."""
+    width = out_ref.shape[0]
+    chunk = min(width, _SEG_CHUNK)
+    neutral = _neutral(op, vals.dtype)
+    col_seg, col_val = seg[:, None], vals[:, None]         # [rows, 1]
+    for c0 in range(0, width, chunk):
+        c1 = min(c0 + chunk, width)
+        ids = jax.lax.broadcasted_iota(jnp.int32, (1, c1 - c0), 1) + (
+            base + c0)
+        sel = jnp.where(col_seg == ids, col_val, neutral)  # [rows, chunk]
+        cur = out_ref[c0:c1]
+        if op == "sum":
+            out_ref[c0:c1] = cur + sel.sum(axis=0, dtype=cur.dtype)
+        elif op == "min":
+            out_ref[c0:c1] = jnp.minimum(cur, sel.min(axis=0))
+        else:
+            out_ref[c0:c1] = jnp.maximum(cur, sel.max(axis=0))
+
+
 def _resident_kernel(seg_ref, val_ref, out_ref, *, op: str):
     i = pl.program_id(0)
 
@@ -55,25 +88,7 @@ def _resident_kernel(seg_ref, val_ref, out_ref, *, op: str):
         out_ref[...] = jnp.full_like(
             out_ref, _neutral(op, out_ref.dtype))
 
-    seg = seg_ref[...]                        # [rows_block] int32
-    vals = val_ref[...]                       # [rows_block, d] f32/i32
-    segs = out_ref.shape[0]
-    onehot = seg[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (1, segs), 1)              # [rows, segs]
-    if op == "sum":
-        # int32 accumulation stays int32 end-to-end (exact — the f32
-        # accumulator would round above 2**24); floats use the MXU.
-        part = jax.lax.dot_general(
-            onehot.astype(vals.dtype), vals,
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=out_ref.dtype)        # [segs, d]
-        out_ref[...] += part
-    else:
-        sel = jnp.where(onehot[:, :, None], vals[:, None, :],
-                        _neutral(op, vals.dtype))        # [rows, segs, d]
-        part = sel.min(axis=0) if op == "min" else sel.max(axis=0)
-        out_ref[...] = (jnp.minimum(out_ref[...], part) if op == "min"
-                        else jnp.maximum(out_ref[...], part))
+    _fold_rows(out_ref, seg_ref[...], val_ref[...], 0, op)
 
 
 def _tiled_kernel(lo_ref, hi_ref, seg_ref, val_ref, out_ref, *, op: str,
@@ -87,29 +102,18 @@ def _tiled_kernel(lo_ref, hi_ref, seg_ref, val_ref, out_ref, *, op: str,
             out_ref, _neutral(op, out_ref.dtype))
 
     base = s * seg_tile
-    blk_lo = lo_ref[0]
-    blk_hi = hi_ref[0]
-    overlap = (blk_lo < base + seg_tile) & (blk_hi >= base)
+    overlap = (lo_ref[r] < base + seg_tile) & (hi_ref[r] >= base)
 
     @pl.when(overlap)
     def _work():
-        seg = seg_ref[...] - base             # [rows_block]
-        vals = val_ref[...]                   # [rows_block, d]
-        onehot = seg[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (1, seg_tile), 1)
-        if op == "sum":
-            part = jax.lax.dot_general(
-                onehot.astype(vals.dtype), vals,
-                (((0,), (0,)), ((), ())),
-                preferred_element_type=out_ref.dtype)
-            out_ref[...] += part
-        else:
-            sel = jnp.where(onehot[:, :, None], vals[:, None, :],
-                            _neutral(op, vals.dtype))
-            part = sel.min(axis=0) if op == "min" else sel.max(axis=0)
-            out_ref[...] = (
-                jnp.minimum(out_ref[...], part) if op == "min"
-                else jnp.maximum(out_ref[...], part))
+        _fold_rows(out_ref, seg_ref[...], val_ref[...], base, op)
+
+
+def _per_column(fn, values):
+    """Apply a 1-D kernel call to each column of ``values`` [n, d]."""
+    if values.shape[1] == 1:
+        return fn(values[:, 0])[:, None]
+    return jax.vmap(fn, in_axes=1, out_axes=1)(values)
 
 
 @functools.partial(
@@ -122,8 +126,8 @@ def segment_reduce_pallas(
                                # (negative or >= num_segments) = dropped
     num_segments: int,
     op: str = "sum",
-    rows_block: int = 512,
-    seg_tile: int = 512,
+    rows_block: int = 1024,
+    seg_tile: int = 1024,
     interpret: bool = False,
 ) -> jax.Array:
     n, d = values.shape
@@ -144,18 +148,21 @@ def segment_reduce_pallas(
         # out-of-range rows -> sacrificial last segment
         ids = jnp.where((seg_ids < 0) | (seg_ids >= num_segments),
                         segs_p - 1, seg_ids)
-        out = pl.pallas_call(
+        call = pl.pallas_call(
             functools.partial(_resident_kernel, op=op),
             grid=(n_pad // rows_block,),
             in_specs=[
                 pl.BlockSpec((rows_block,), lambda i: (i,)),
-                pl.BlockSpec((rows_block, d), lambda i: (i, 0)),
+                pl.BlockSpec((rows_block,), lambda i: (i,)),
             ],
-            out_specs=pl.BlockSpec((segs_p, d), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((segs_p, d), acc_dtype),
+            # int32 block index: a literal 0 would trace as int64 under
+            # x64, which Mosaic cannot return from an index map
+            out_specs=pl.BlockSpec((segs_p,),
+                                   lambda i: (jnp.zeros((), jnp.int32),)),
+            out_shape=jax.ShapeDtypeStruct((segs_p,), acc_dtype),
             interpret=interpret,
-        )(ids, values)
-        return out[:num_segments]
+        )
+        return _per_column(lambda v: call(ids, v), values)[:num_segments]
 
     segs_p = pl.cdiv(num_segments, seg_tile) * seg_tile + seg_tile
     ids = jnp.where((seg_ids < 0) | (seg_ids >= num_segments),
@@ -167,17 +174,18 @@ def segment_reduce_pallas(
         (blk < segs_p - 1).any(axis=1),
         jnp.where(blk < segs_p - 1, blk, -1).max(axis=1), -1
     ).astype(jnp.int32)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_tiled_kernel, op=op, seg_tile=seg_tile),
-        grid=(segs_p // seg_tile, nblocks),
-        in_specs=[
-            pl.BlockSpec((1,), lambda s, r: (r,)),
-            pl.BlockSpec((1,), lambda s, r: (r,)),
-            pl.BlockSpec((rows_block,), lambda s, r: (r,)),
-            pl.BlockSpec((rows_block, d), lambda s, r: (r, 0)),
-        ],
-        out_specs=pl.BlockSpec((seg_tile, d), lambda s, r: (s, 0)),
-        out_shape=jax.ShapeDtypeStruct((segs_p, d), acc_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(segs_p // seg_tile, nblocks),
+            in_specs=[
+                pl.BlockSpec((rows_block,), lambda s, r, *_: (r,)),
+                pl.BlockSpec((rows_block,), lambda s, r, *_: (r,)),
+            ],
+            out_specs=pl.BlockSpec((seg_tile,), lambda s, r, *_: (s,))),
+        out_shape=jax.ShapeDtypeStruct((segs_p,), acc_dtype),
         interpret=interpret,
-    )(blk_lo, blk_hi, ids, values)
-    return out[:num_segments]
+    )
+    return _per_column(lambda v: call(blk_lo, blk_hi, ids, v),
+                       values)[:num_segments]

@@ -17,14 +17,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def use_mesh(mesh):
-    """Version-portable 'make this mesh active' context manager:
-    ``jax.set_mesh`` where it exists, the mesh's own thread-local
-    context manager (``with mesh:``) on older JAX — which is exactly
+    """'Make this mesh active' context manager (``jax.set_mesh``) —
     what ``models.common.active_abstract_mesh`` reads back."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    return jax.set_mesh(mesh)
 
 
 def make_local_mesh():

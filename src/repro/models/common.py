@@ -85,23 +85,10 @@ def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
 
 
 def active_abstract_mesh():
-    """Version-portable query for the active (abstract) mesh.
-
-    ``jax.sharding.get_abstract_mesh`` only exists in newer JAX; older
-    releases track the active mesh in the pxla thread-local set by
-    ``with mesh:``. Returns an object with ``axis_names``/``axis_sizes``
-    or None when no mesh is active (CPU smoke tests)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    try:
-        from jax.interpreters import pxla
-        phys = pxla.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        return None
-    if phys is None or phys.empty:
-        return None
-    return getattr(phys, "abstract_mesh", phys)
+    """The active (abstract) mesh set by ``jax.set_mesh``: an object
+    with ``axis_names``/``axis_sizes``, empty when no mesh is active
+    (CPU smoke tests)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def maybe_shard(x, *entries):

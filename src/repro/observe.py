@@ -150,7 +150,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--size", type=int, default=64,
                     help="demo graph node count (default 64)")
     ap.add_argument("--mode", choices=("host", "device"), default="host")
-    ap.add_argument("--backend", choices=("jnp", "pallas"), default="jnp")
+    ap.add_argument("--backend", choices=("jnp", "pallas", "pallas-interpret"),
+                    default="jnp")
     ap.add_argument("--shards", type=int, default=0)
     ap.add_argument("--updates", type=int, default=0,
                     help="also run N incremental update batches and "
@@ -166,6 +167,8 @@ def main(argv: list[str] | None = None) -> int:
 
     if args.check:
         return _check(args.check)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     return _run_demo(args)
 
 

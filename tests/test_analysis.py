@@ -404,7 +404,8 @@ def test_shard_layer_named_in_error():
     assert "layer 'shard'" in str(exc.value)
 
 
-@pytest.mark.parametrize("backend", ("jnp", "pallas"))
+@pytest.mark.parametrize("backend", (
+    "jnp", pytest.param("pallas-interpret", id="pallas")))
 def test_run_sanitizer_clean_backends(backend):
     """check_invariants=True full runs stay clean on both kernel
     backends."""

@@ -306,8 +306,8 @@ def test_fixpoint_equivalence_pallas(program):
     kernels (interpret mode) pins the same equivalence."""
     src, edbs = _datasets()[program]
     _run_pair(src, edbs,
-              on_cfg=_cfg(True, kernel_backend="pallas"),
-              off_cfg=_cfg(False, kernel_backend="pallas"))
+              on_cfg=_cfg(True, kernel_backend="pallas-interpret"),
+              off_cfg=_cfg(False, kernel_backend="pallas-interpret"))
 
 
 def test_fixpoint_equivalence_device_mode():

@@ -39,7 +39,7 @@ def test_fixpoint_backend_equivalence(program):
     out_j, st_j = Engine(compile_program(src),
                          _cfg("jnp")).run(dict(edbs))
     out_p, st_p = Engine(compile_program(src),
-                         _cfg("pallas")).run(dict(edbs))
+                         _cfg("pallas-interpret")).run(dict(edbs))
     assert out_j.keys() == out_p.keys()
     for name in out_j:
         np.testing.assert_array_equal(out_j[name], out_p[name])
@@ -53,7 +53,8 @@ def test_fixpoint_backend_equivalence_device_mode():
     out_j, st_j = Engine(compile_program(src),
                          _cfg("jnp", mode="device")).run(dict(edbs))
     out_p, st_p = Engine(compile_program(src),
-                         _cfg("pallas", mode="device")).run(dict(edbs))
+                         _cfg("pallas-interpret", mode="device")).run(
+                             dict(edbs))
     np.testing.assert_array_equal(out_j["tc"], out_p["tc"])
     assert st_j.iterations == st_p.iterations
 
@@ -61,13 +62,17 @@ def test_fixpoint_backend_equivalence_device_mode():
 def test_resolve_backend():
     assert resolve_backend("jnp") is JNP
     assert isinstance(resolve_backend("jnp"), JnpDispatch)
-    pb = resolve_backend("pallas")
-    assert isinstance(pb, PallasDispatch)
-    # no TPU in CI: auto falls back to jnp, pallas means interpret
+    pb = resolve_backend("pallas-interpret")
+    assert isinstance(pb, PallasDispatch) and pb.interpret
+    # no TPU in CI: auto falls back to jnp, and "pallas" (compiled
+    # kernels) refuses instead of silently interpreting
     import jax
     if jax.default_backend() != "tpu":
         assert isinstance(resolve_backend("auto"), JnpDispatch)
-        assert pb.interpret
+        with pytest.raises(RuntimeError, match="pallas-interpret"):
+            resolve_backend("pallas")
+    else:
+        assert not resolve_backend("pallas").interpret
     assert resolve_backend(pb) is pb        # pass-through
     assert type(resolve_backend(None)) is type(resolve_backend("auto"))
     with pytest.raises(ValueError):

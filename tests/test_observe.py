@@ -224,7 +224,7 @@ def test_observe_off_identical_jnp(program):
 
 def test_observe_off_identical_pallas():
     src, edbs = equivalence_datasets()["TC"]
-    _run_pair(src, edbs, kernel_backend="pallas")
+    _run_pair(src, edbs, kernel_backend="pallas-interpret")
 
 
 def test_observe_off_identical_device_mode():

@@ -119,7 +119,7 @@ def test_fault_point_is_noop_without_plan():
 @pytest.mark.slow
 def test_crash_replay_pallas():
     crashes = _run_crash_replay_stream(
-        "TC", backend="pallas", n_steps=5, seed=33, n_crashes=3)
+        "TC", backend="pallas-interpret", n_steps=5, seed=33, n_crashes=3)
     assert crashes >= 1
 
 

@@ -123,7 +123,7 @@ def test_sharded_composes_with_pallas_backend():
     _need(2)
     src, edbs = _datasets()["TC"]
     _assert_equivalent(src, edbs,
-                       _cfg(shards=2, kernel_backend="pallas"))
+                       _cfg(shards=2, kernel_backend="pallas-interpret"))
 
 
 def test_sharded_skewed_keys():

@@ -44,9 +44,12 @@ STREAM_PLAN = (
     ("TC", "jnp", 70, 101),
     ("Negation", "jnp", 25, 102),
     ("WideReach2", "jnp", 45, 103),
-    ("TC", "pallas", 40, 104),
-    ("WideReach2", "pallas", 25, 105),
+    ("TC", "pallas-interpret", 40, 104),
+    ("WideReach2", "pallas-interpret", 25, 105),
 )
+# test ids name the kernels ("pallas"), not their interpret mode
+STREAM_IDS = ["-".join(map(str, p)).replace("pallas-interpret", "pallas")
+              for p in STREAM_PLAN]
 
 _SABOTAGE_ROW_VALUE = 1_000_003  # far outside every corpus domain
 
@@ -221,7 +224,8 @@ def _run_stream(program: str, backend: str = "jnp", n_steps: int = 20,
     return executed
 
 
-@pytest.mark.parametrize("program,backend,n_steps,seed", STREAM_PLAN)
+@pytest.mark.parametrize("program,backend,n_steps,seed", STREAM_PLAN,
+                         ids=STREAM_IDS)
 def test_update_stream_matches_batch(program, backend, n_steps, seed):
     """>= 200 randomized differential steps across the plan: every
     step's post-update state byte-matches a from-scratch recompute."""
@@ -447,7 +451,7 @@ def test_sharded_update_stream_wide(shards):
 
 def test_sharded_update_stream_pallas():
     """sharded x pallas x incremental composes (interpret mode on CPU)."""
-    _run_sharded_stream("TC", 2, backend="pallas", n_steps=4, seed=13)
+    _run_sharded_stream("TC", 2, backend="pallas-interpret", n_steps=4, seed=13)
 
 
 def test_sharded_monoid_recompute_fallback():

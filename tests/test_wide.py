@@ -362,7 +362,8 @@ def test_wide_agg_matches_python_groupby():
 
 # -- forced multi-word on the narrow corpus ----------------------------------
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("backend", [
+    "jnp", pytest.param("pallas-interpret", id="pallas")])
 @pytest.mark.parametrize("program", ["TC", "SG", "Count", "Negation"])
 def test_force_multiword_narrow_equivalence(program, backend):
     """The fast-path guarantee from the other side: pushing narrow
