@@ -29,13 +29,14 @@ engine state each device holds.
 
 Each phase prints cold seconds (with compilation), warm seconds (a
 second run on the same engine; none for ``--chips 4``), iterations,
-fact counts, ``grow_retries``, the device's ``peak_bytes_in_use`` and
-the seconds spent compiling. The cold fixpoint runs of (a), with the
-cold half of (b), and those of ``--chips 4`` go in parallel threads so
-that their compiles overlap; their seconds overlap too. These are smoke
-numbers, not benchmark results. Any mismatch or error exits non-zero;
-without a TPU the script exits non-zero before running anything. The last line of a passing run is the JSON object
-``{"ok": true, "device": {...}}``.
+fact counts, ``grow_retries``, the device's ``peak_bytes_in_use`` and,
+beside each time, the seconds that call spent compiling. The cold
+fixpoint runs of (a), with the cold half of (b), and those of
+``--chips 4`` go in parallel threads so that their compiles overlap;
+their seconds overlap too. These are smoke numbers, not benchmark
+results. Any mismatch or error exits non-zero; without a TPU the script
+exits non-zero before running anything. The last line of a passing run
+is the JSON object ``{"ok": true, "device": {...}}``.
 """
 from __future__ import annotations
 
@@ -55,10 +56,12 @@ REPO = Path(__file__).resolve().parent
 KRONECKER = (0.57, 0.19, 0.19, 0.05)
 EDGE_FACTOR = 16
 SOURCE_SCALE = 22
-# scale 16 took 1272 s cold on one v5e (over the 1200 s budget), most
-# of it compiling; scale 14 keeps the cold run well inside it
-DEFAULT_SCALE = 14
+# the run must end within 1200 s with a cold compile cache. On one v5e,
+# scale 15 took 708 s; scale 16 was still in phase (a) at 1000 s, most
+# of it compiling, then 157 s per warm CC run
+DEFAULT_SCALE = 15
 BATCH_EDGES = 1000
+BATCHES = 4          # incremental batches, inserts and deletes alternating
 
 
 def log(msg: str) -> None:
@@ -177,38 +180,48 @@ def peak_bytes(device) -> int:
 class Clock:
     """Wall seconds, and the seconds XLA spent compiling, summed from
     JAX's backend-compile monitoring event: on a TPU, compiling the
-    engine's sorts is a large share of a cold run."""
+    engine's sorts is a large share of a cold run. JAX compiles in the
+    thread that calls, so compile seconds are also kept per thread and
+    a timed call counts only its own, even beside parallel jobs."""
 
     EVENT = "/jax/core/compile/backend_compile_duration"
 
     def __init__(self):
         import jax
         self.compile_s = 0.0
+        self._by_thread: dict[int, float] = {}
         self._lock = threading.Lock()    # compiles report from threads
         jax.monitoring.register_event_duration_secs_listener(self._on)
 
     def _on(self, event: str, secs: float, **_) -> None:
         if event == self.EVENT:
+            tid = threading.get_ident()
             with self._lock:
                 self.compile_s += secs
+                self._by_thread[tid] = self._by_thread.get(tid, 0.0) + secs
+
+    def _own(self) -> float:
+        with self._lock:
+            return self._by_thread.get(threading.get_ident(), 0.0)
 
     def timed(self, fn):
-        """(fn(), wall seconds, seconds of it spent compiling)."""
-        t0, c0 = time.perf_counter(), self.compile_s
+        """(fn(), wall seconds, seconds this thread spent compiling)."""
+        t0, c0 = time.perf_counter(), self._own()
         out = fn()
-        return out, time.perf_counter() - t0, self.compile_s - c0
+        return out, time.perf_counter() - t0, self._own() - c0
 
     def cold_runs(self, jobs: dict) -> tuple[dict, float]:
         """Run each job (key -> no-argument callable) once, all in
         parallel threads, so that their XLA compiles overlap: compiled
         one after another they would be most of a cold run. Returns
-        ({key: (result, wall seconds)}, compile seconds of the batch)."""
+        ({key: (result, wall seconds, compile seconds)}, compile seconds
+        of all the jobs)."""
         from concurrent.futures import ThreadPoolExecutor
         c0 = self.compile_s
         with ThreadPoolExecutor(len(jobs)) as pool:
             futures = {k: pool.submit(self.timed, fn)
                        for k, fn in jobs.items()}
-            done = {k: f.result()[:2] for k, f in futures.items()}
+            done = {k: f.result() for k, f in futures.items()}
         return done, self.compile_s - c0
 
 
@@ -231,8 +244,8 @@ def phase_batch(w: Workload, device, backend, clock: Clock,
         f"compile_s={compile_s:.3f}")
     wants = {p: w.reference(p) for p in outs}
     for (program, mode), eng in engines.items():
-        (out, stats), cold_s = cold[program, mode]
-        (out2, stats2), warm_s, _ = clock.timed(
+        (out, stats), cold_s, cold_c = cold[program, mode]
+        (out2, stats2), warm_s, warm_c = clock.timed(
             lambda: eng.run(w.edbs(program)))
         for o, st in ((out, stats), (out2, stats2)):
             check(f"(a) {program} {mode}", o[outs[program]], wants[program])
@@ -240,7 +253,8 @@ def phase_batch(w: Workload, device, backend, clock: Clock,
                 raise AssertionError(f"(a) {program} {mode}: grow_retries "
                                      f"{st.grow_retries}")
         log(f"phase a  {program:5s} mode={mode:6s} cold_s={cold_s:.3f} "
-            f"warm_s={warm_s:.3f} iterations={stats2.total_iterations} "
+            f"(compile_s={cold_c:.3f}) warm_s={warm_s:.3f} "
+            f"(compile_s={warm_c:.3f}) iterations={stats2.total_iterations} "
             f"facts={stats2.total_facts[outs[program]]} "
             f"grow_retries={stats2.grow_retries} "
             f"peak_bytes_in_use={peak_bytes(device)} ok=true")
@@ -259,18 +273,19 @@ class IncrementalReach:
                                incremental=True)
         self.edges = np.unique(w.edges, axis=0)
 
-    def _log(self, what: str, out, secs: float, note: str) -> None:
-        log(f"phase b  {what}{secs:.3f}{note} "
+    def _log(self, what: str, out, secs: float, compile_s: float,
+             note: str) -> None:
+        log(f"phase b  {what}{secs:.3f} (compile_s={compile_s:.3f}){note} "
             f"facts={len(out['reach'])} "
             f"grow_retries={self.inc._stats.grow_retries} "
             f"peak_bytes_in_use={peak_bytes(self.device)} ok=true")
 
     def initialize(self, note: str = "") -> None:
-        out, s, _ = self.clock.timed(lambda: self.inc.initialize(
+        out, s, c = self.clock.timed(lambda: self.inc.initialize(
             self.w.edbs("Reach", self.edges)))
         check("(b) initialize", out["reach"],
               self.w.reference("Reach", self.edges))
-        self._log("initialize cold_s=", out, s, note)
+        self._log("initialize cold_s=", out, s, c, note)
 
     def batch(self, i: int, label: str, note: str = "") -> None:
         edges, w = self.edges, self.w
@@ -288,12 +303,12 @@ class IncrementalReach:
             keep[np.searchsorted(edges[:, 0] * w.n + edges[:, 1],
                                  rows[:, 0] * w.n + rows[:, 1])] = False
             edges = edges[keep]
-        out, s, _ = self.clock.timed(lambda: self.inc.apply(**update))
+        out, s, c = self.clock.timed(lambda: self.inc.apply(**update))
         self.edges = edges
         check(f"(b) {kind} batch {i}", out["reach"],
               w.reference("Reach", edges))
         self._log(f"{kind:6s} batch={i} rows={BATCH_EDGES} {label}_s=",
-                  out, s, note)
+                  out, s, c, note)
 
 
 def _ref_segment(vals, seg, num_segments, op):
@@ -407,8 +422,8 @@ def phase_sharded(w: Workload, devices, backend, clock: Clock) -> None:
     log(f"sharded  4 cold runs in parallel: compile_s={compile_s:.3f}")
     for program, out_name in outs.items():
         want = w.reference(program)
-        (out1, st1), s1 = cold[program, 0]
-        (out4, st4), s4 = cold[program, shards]
+        (out1, st1), s1, _ = cold[program, 0]
+        (out4, st4), s4, _ = cold[program, shards]
         check(f"{program} single", out1[out_name], want)
         check(f"{program} sharded", out4[out_name], want)
         for name in out1:
@@ -440,8 +455,6 @@ def main(argv=None) -> int:
     ap.add_argument("--scale", type=int, default=DEFAULT_SCALE,
                     help=f"Graph500 scale (default {DEFAULT_SCALE})")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--batches", type=int, default=4,
-                    help="incremental batches, alternating insert/delete")
     args = ap.parse_args(argv)
     if not 1 <= args.scale <= 21:
         ap.error("--scale must be 1..21: 3-column key words hold vertex "
@@ -491,18 +504,19 @@ def main(argv=None) -> int:
     else:
         # the cold half of (b) (initialize and the first insert and
         # delete batch, which compile the maintenance passes) runs in
-        # parallel with the cold runs of (a); the later batches reuse
-        # those programs and run alone
+        # parallel with the cold runs of (a); the later batches run
+        # alone, and their compile_s shows what they still compile (a
+        # DRed round at a new frontier capacity is a new program)
         inc = IncrementalReach(w, device, "auto", clock)
 
         def inc_cold():
             note = " (parallel with phase a)"
             inc.initialize(note)
-            for i in range(min(2, args.batches)):
+            for i in range(2):
                 inc.batch(i, "cold", note)
 
         phase_batch(w, device, "auto", clock, also=inc_cold)
-        for i in range(2, args.batches):
+        for i in range(2, BATCHES):
             inc.batch(i, "warm")
         phase_kernels(w, device, clock)
     log(f"total_s={time.perf_counter() - t_start:.3f} "

@@ -53,8 +53,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import ref
-
 
 def _chunk_lex_lt_le(a_chunks, b_chunks):
     """Fold a lexicographic (lt, le) compare over a static sequence of
@@ -109,14 +107,34 @@ def _count_true(mask):
     return mask.astype(jnp.int32).sum(axis=1, dtype=jnp.int32)
 
 
-def _block_bounds(build_min, build_max, probe_min, probe_max, ranks):
+def _block_rank(build, probe, upper: bool):
+    """Per probe row, the number of ``build`` rows below it (``upper``:
+    at or below it). ``build`` is sorted: [k] int64 keys, or [k, W]
+    int64 words in lexicographic order, searched by a vectorized binary
+    search (``bit_length(k)`` unrolled steps)."""
+    if build.ndim == 1:
+        return jnp.searchsorted(build, probe,
+                                side="right" if upper else "left")
+    k = build.shape[0]
+    lo = jnp.zeros(probe.shape[:1], jnp.int32)
+    hi = jnp.full(probe.shape[:1], k, jnp.int32)
+    for _ in range(k.bit_length()):
+        mid = (lo + hi) >> 1
+        rows = jnp.take(build, mid, axis=0, mode="clip")
+        lt, le = _chunk_lex_lt_le(list(rows.T), list(probe.T))
+        active, below = lo < hi, le if upper else lt
+        lo = jnp.where(active & below, mid + 1, lo)
+        hi = jnp.where(active & ~below, mid, hi)
+    return lo
+
+
+def _block_bounds(build_min, build_max, probe_min, probe_max):
     """Per probe block, the build-block range ``[r_lo, r_hi)`` that needs
     compares: ``r_lo`` = #build blocks whose max is below the probe
     block's min, ``r_hi`` = #build blocks whose min is at or below its
-    max. ``ranks(build, probe) -> (lo, hi)`` is searchsorted left/right
-    over the sorted block keys."""
-    r_lo, _ = ranks(build_max, probe_min)
-    _, r_hi = ranks(build_min, probe_max)
+    max."""
+    r_lo = _block_rank(build_max, probe_min, upper=False)
+    r_hi = _block_rank(build_min, probe_max, upper=True)
     return r_lo.astype(jnp.int32), r_hi.astype(jnp.int32)
 
 
@@ -176,7 +194,7 @@ def merge_probe_pallas(
     bblk = build_keys.reshape(nb, build_block)
     pblk = probe_keys.reshape(n_pad // probe_block, probe_block)
     bounds = _block_bounds(bblk[:, 0], bblk[:, -1], pblk[:, 0],
-                           pblk[:, -1], ref.merge_probe_ref)
+                           pblk[:, -1])
     lo, hi = _probe_call(split(probe_keys), split(build_keys), bounds,
                          probe_block, build_block, interpret)
     # padded build rows carry MAXK; probes that are real never count them
@@ -276,7 +294,7 @@ def merge_probe_multi_pallas(
     bblk = build_words.reshape(nb, build_block, w)
     pblk = probe_words.reshape(n_pad // probe_block, probe_block, w)
     bounds = _block_bounds(bblk[:, 0], bblk[:, -1], pblk[:, 0],
-                           pblk[:, -1], ref.merge_probe_multi_ref)
+                           pblk[:, -1])
     lo, hi = _probe_call(split(probe_words), split(build_words), bounds,
                          probe_block, build_block, interpret)
     # padded build rows carry MAXK in every word; real probes never
