@@ -13,13 +13,12 @@ from pathlib import Path
 
 ALL_TABLES = ("table1", "seminaive", "robustness", "specialization",
               "incremental", "kernels", "backends", "sharding", "wide",
-              "arrange", "observe", "resilience", "roofline")
+              "arrange", "resilience", "roofline")
 
 # the cheap tables --smoke runs by default (CI bitrot guard: the bench
 # harness executes end-to-end on every push, in seconds; resilience
 # rides along so the crash-replay differential runs on every push)
-SMOKE_TABLES = ("arrange", "incremental", "robustness", "observe",
-                "resilience")
+SMOKE_TABLES = ("arrange", "incremental", "robustness", "resilience")
 
 
 def collect(only=None, smoke: bool = False) -> list[dict]:
@@ -56,9 +55,6 @@ def collect(only=None, smoke: bool = False) -> list[dict]:
     if "arrange" in only:
         from benchmarks.arrange import bench as bench_arrange
         rows += bench_arrange(smoke=smoke)
-    if "observe" in only:
-        from benchmarks.observe import bench as bench_observe
-        rows += bench_observe(smoke=smoke)
     if "resilience" in only:
         from benchmarks.resilience import bench as bench_resilience
         rows += bench_resilience(smoke=smoke)

@@ -9,9 +9,7 @@ from repro.engine.engine import Engine, EngineConfig, EngineStats
 from repro.engine.faults import (
     FaultError, FaultPlan, FaultSpec, SimulatedCrash,
 )
-from repro.engine.observe import (
-    REGISTRY, MetricsRegistry, Observation, validate_chrome_trace,
-)
+from repro.engine.observe import REGISTRY, MetricsRegistry, Observation
 
 
 def make_engine(compiled, config: EngineConfig | None = None,
@@ -51,7 +49,7 @@ __all__ = [
     "resolve_backend",
     "Engine", "EngineConfig", "EngineStats", "make_engine",
     "FaultError", "FaultPlan", "FaultSpec", "SimulatedCrash",
-    "REGISTRY", "MetricsRegistry", "Observation", "validate_chrome_trace",
+    "REGISTRY", "MetricsRegistry", "Observation",
     "DurableIncrementalEngine", "ResilienceConfig", "SnapshotMismatch",
     "UpdateLog",
 ]

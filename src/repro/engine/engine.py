@@ -763,13 +763,15 @@ class Engine:
                     strata=len(self.compiled.strata),
                     mode=self.cfg.mode, shards=self.cfg.shards or 1,
                     backend=type(self.backend).__name__):
-            env_rels = self._edb_env(edbs, edb_caps)
+            with O.span(self.cfg.observe, "edb-load"):
+                env_rels = self._edb_env(edbs, edb_caps)
 
             for sp in self.compiled.strata:
                 env_rels = self._run_stratum(
                     sp, env_rels, stats, f"s{sp.index}")
 
-            out = self._export(env_rels, stats)
+            with O.span(self.cfg.observe, "export"):
+                out = self._export(env_rels, stats)
         stats.wall_s = time.perf_counter() - t0
         self.last_env = env_rels
         return out, stats

@@ -234,95 +234,127 @@ class IncrementalEngine:
             if name not in self.compiled.edbs:
                 raise ValueError(f"{name} is not an EDB")
 
-        # apply to base EDB sets
-        real_ins: dict[str, np.ndarray] = {}
-        real_del: dict[str, np.ndarray] = {}
-        for name, rows in inserts.items():
-            rows = _row_tuples(rows)
-            new = [r for r in rows if r not in self.edbs.setdefault(
-                name, set())]
-            self.edbs[name] |= set(new)
-            if new:
-                real_ins[name] = np.array(sorted(set(new)))
-        for name, rows in deletes.items():
-            rows = _row_tuples(rows)
-            old = [r for r in rows if r in self.edbs.get(name, set())]
-            self.edbs[name] -= set(old)
-            if old:
-                real_del[name] = np.array(sorted(set(old)))
-        changed = set(real_ins) | set(real_del)
-        if not changed:
-            return self.snapshot()
-
         obs = self.engine.cfg.observe
-        idb_delta_rows = 0
-        with O.span(obs, "apply",
+        # an update that changes nothing still spans its mirror and its
+        # snapshot, host time a profiler's record should name
+        with O.span(obs, "apply") as ap_span:
+            with O.span(obs, "mirror") as mirror_span:
+                ins, dels = self._mirror(inserts, deletes)
+                real_ins = {k: np.array(sorted(v)) for k, v in ins.items()}
+                real_del = {k: np.array(sorted(v)) for k, v in dels.items()}
+            changed = set(real_ins) | set(real_del)
+            if ap_span is not None:
+                ap_span.attrs.update(
                     changed=",".join(sorted(changed)),
                     insert_rows=sum(len(v) for v in real_ins.values()),
-                    delete_rows=sum(len(v) for v in real_del.values()),
-                    ) as ap_span:
-            affected: set[int] = set()
-            for name in changed:
-                affected |= self._downstream.get(name, set())
+                    delete_rows=sum(len(v) for v in real_del.values()))
+            idb_delta_rows = (self._maintain(real_ins, real_del)
+                              if changed else None)
+            with O.span(obs, "snapshot") as snap_span:
+                out = self.snapshot()
+        if obs is not None and changed:
+            # per-update maintenance latency: the apply less its mirror
+            # update and its final export, so this is maintenance cost,
+            # not numpy export cost; and IDB-level churn per update
+            obs.registry.observe(
+                "update.latency_s",
+                ap_span.dur - mirror_span.dur - snap_span.dur)
+            obs.registry.observe("update.delta_rows", idb_delta_rows)
+        return out
 
-            # refresh EDB relations in env (stored form: the sharded
-            # driver scatters each to its home shards)
-            for name in changed:
-                self._refresh_edb(name)
+    def _mirror(self, inserts: dict, deletes: dict
+                ) -> tuple[dict[str, set], dict[str, set]]:
+        """Apply an update batch to the host EDB sets; returns the rows
+        that really were inserted and deleted, per relation that
+        changed."""
+        real_ins: dict[str, set] = {}
+        real_del: dict[str, set] = {}
+        for name, rows in inserts.items():
+            have = self.edbs.setdefault(name, set())
+            new = {r for r in _row_tuples(rows) if r not in have}
+            if new:
+                have |= new
+                real_ins[name] = new
+        for name, rows in deletes.items():
+            have = self.edbs.get(name, set())
+            old = {r for r in _row_tuples(rows) if r in have}
+            if old:
+                have -= old
+                real_del[name] = old
+        return real_ins, real_del
 
-            # change sets grow as strata update (IDB-level diffs feed
-            # downstream)
-            ins_changes: dict[str, np.ndarray] = dict(real_ins)
-            del_changes: dict[str, np.ndarray] = dict(real_del)
-            for sp in self.compiled.strata:
-                if sp.index not in affected:
-                    continue
-                consumed = self._consumes[sp.index]
-                my_ins = {k: v for k, v in ins_changes.items()
-                          if k in consumed}
-                my_del = {k: v for k, v in del_changes.items()
-                          if k in consumed}
-                if not my_ins and not my_del:
-                    continue
+    def _maintain(self, real_ins: dict[str, np.ndarray],
+                  real_del: dict[str, np.ndarray]) -> int:
+        """Bring the stored EDBs and every affected stratum up to the
+        changed EDB sets; returns the IDB rows added and removed."""
+        obs = self.engine.cfg.observe
+        changed = set(real_ins) | set(real_del)
+        idb_delta_rows = 0
+        affected: set[int] = set()
+        for name in changed:
+            affected |= self._downstream.get(name, set())
+
+        # refresh EDB relations in env (stored form: the sharded
+        # driver scatters each to its home shards)
+        for name in changed:
+            self._refresh_edb(name)
+
+        # change sets grow as strata update (IDB-level diffs feed
+        # downstream)
+        ins_changes: dict[str, np.ndarray] = dict(real_ins)
+        del_changes: dict[str, np.ndarray] = dict(real_del)
+        for sp in self.compiled.strata:
+            if sp.index not in affected:
+                continue
+            consumed = self._consumes[sp.index]
+            my_ins = {k: v for k, v in ins_changes.items()
+                      if k in consumed}
+            my_del = {k: v for k, v in del_changes.items()
+                      if k in consumed}
+            if not my_ins and not my_del:
+                continue
+            with O.span(obs, "idb-diff", key=f"s{sp.index}",
+                        side="before"):
                 old_snap = {n: self._snapshot_idb(n) for n in sp.idbs}
-                monoid_hit = any(n in self.compiled.monoid_idbs
-                                 for n in sp.idbs)
-                # stratified aggregates (Reduce) are order-sensitive in
-                # their inputs: seeds over changed subsets would
-                # aggregate partial groups. Non-recursive agg strata are
-                # one pass — recompute. Exception: a Reduce feeding a
-                # MIN/MAX monoid IDB is seed-safe (a partial-subset MIN
-                # monoid-merges to the true MIN).
-                agg_hit = any(
-                    isinstance(n, I.Reduce)
-                    for p in sp.plans
-                    if p.head not in self.compiled.monoid_idbs
-                    for n in I.iter_nodes(p.root))
-                # a change to a relation this stratum NEGATES is
-                # inverted and non-monotone on the head (delete of a
-                # negated fact adds head facts; insert retracts them) —
-                # seeds cannot express either, so recompute (still
-                # through the driver: sharded engines recompute
-                # shard-local)
-                neg_hit = bool((set(my_ins) | set(my_del))
-                               & self._neg_consumes[sp.index])
-                if agg_hit or neg_hit or (my_del and monoid_hit):
-                    strategy = "recompute"
-                elif my_del:
-                    strategy = "dred"
+            monoid_hit = any(n in self.compiled.monoid_idbs
+                             for n in sp.idbs)
+            # stratified aggregates (Reduce) are order-sensitive in
+            # their inputs: seeds over changed subsets would aggregate
+            # partial groups. Non-recursive agg strata are one pass —
+            # recompute. Exception: a Reduce feeding a MIN/MAX monoid
+            # IDB is seed-safe (a partial-subset MIN monoid-merges to
+            # the true MIN).
+            agg_hit = any(
+                isinstance(n, I.Reduce)
+                for p in sp.plans
+                if p.head not in self.compiled.monoid_idbs
+                for n in I.iter_nodes(p.root))
+            # a change to a relation this stratum NEGATES is inverted
+            # and non-monotone on the head (delete of a negated fact
+            # adds head facts; insert retracts them) — seeds cannot
+            # express either, so recompute (still through the driver:
+            # sharded engines recompute shard-local)
+            neg_hit = bool((set(my_ins) | set(my_del))
+                           & self._neg_consumes[sp.index])
+            if agg_hit or neg_hit or (my_del and monoid_hit):
+                strategy = "recompute"
+            elif my_del:
+                strategy = "dred"
+            else:
+                strategy = "seed-insert"
+            with O.span(obs, "maintain-stratum",
+                        key=f"s{sp.index}", strategy=strategy):
+                F.fault_point("incremental.maintain")
+                O.count(obs, f"incremental.{strategy}")
+                if strategy == "recompute":
+                    self._recompute_stratum(sp)
+                elif strategy == "dred":
+                    self._dred_stratum(sp, my_ins, my_del)
                 else:
-                    strategy = "seed-insert"
-                with O.span(obs, "maintain-stratum",
-                            key=f"s{sp.index}", strategy=strategy):
-                    F.fault_point("incremental.maintain")
-                    O.count(obs, f"incremental.{strategy}")
-                    if strategy == "recompute":
-                        self._recompute_stratum(sp)
-                    elif strategy == "dred":
-                        self._dred_stratum(sp, my_ins, my_del)
-                    else:
-                        self._insert_stratum(sp, my_ins)
-                # IDB-level diffs for downstream strata
+                    self._insert_stratum(sp, my_ins)
+            # IDB-level diffs for downstream strata
+            with O.span(obs, "idb-diff", key=f"s{sp.index}",
+                        side="after"):
                 for n in sp.idbs:
                     new_snap = self._snapshot_idb(n)
                     old_set = set(map(tuple, old_snap[n]))
@@ -334,21 +366,15 @@ class IncrementalEngine:
                         ins_changes[n] = np.array(added)
                     if removed:
                         del_changes[n] = np.array(removed)
-            # maintained arrangements must satisfy the same contract a
-            # batch run would leave behind (core/analysis/sanitize.py);
-            # the recompute/fixpoint paths were checked per-stratum
-            # already — this covers the seed-merge and DRed update paths
-            if self.engine._sanitize_due():
-                from repro.core.analysis.sanitize import sanitize_env
-                sanitize_env(self.engine, self._env, "incremental apply",
-                             "incremental")
-        if obs is not None:
-            # per-update maintenance latency (span closes before the
-            # final snapshot export, so this is maintenance cost, not
-            # numpy export cost) + IDB-level churn per update
-            obs.registry.observe("update.latency_s", ap_span.dur)
-            obs.registry.observe("update.delta_rows", idb_delta_rows)
-        return self.snapshot()
+        # maintained arrangements must satisfy the same contract a
+        # batch run would leave behind (core/analysis/sanitize.py); the
+        # recompute/fixpoint paths were checked per-stratum already —
+        # this covers the seed-merge and DRed update paths
+        if self.engine._sanitize_due():
+            from repro.core.analysis.sanitize import sanitize_env
+            sanitize_env(self.engine, self._env, "incremental apply",
+                         "incremental")
+        return idb_delta_rows
 
     def _rows(self, rel) -> np.ndarray:
         """Stored relation -> host rows (the one gather point)."""
@@ -393,9 +419,10 @@ class IncrementalEngine:
     def _refresh_edb(self, name: str) -> None:
         """Mirror -> stored EDB relation in the env (the sharded driver
         scatters to home shards)."""
-        rows = self._edb_rows(name)
-        self._env[(name, I.FULL)] = self.engine._stored(
-            {name: from_numpy(rows, pow2_cap(len(rows)))})[name]
+        with O.span(self.engine.cfg.observe, "refresh-edb", relation=name):
+            rows = self._edb_rows(name)
+            self._env[(name, I.FULL)] = self.engine._stored(
+                {name: from_numpy(rows, pow2_cap(len(rows)))})[name]
 
     # -- recompute rungs (engine/resilience.py degradation ladder) -------------
     def apply_base(self, inserts: Optional[dict] = None,
@@ -412,19 +439,8 @@ class IncrementalEngine:
         for name in set(inserts) | set(deletes):
             if name not in self.compiled.edbs:
                 raise ValueError(f"{name} is not an EDB")
-        changed: set[str] = set()
-        for name, rows in inserts.items():
-            new = [r for r in _row_tuples(rows)
-                   if r not in self.edbs.setdefault(name, set())]
-            if new:
-                self.edbs[name] |= set(new)
-                changed.add(name)
-        for name, rows in deletes.items():
-            old = [r for r in _row_tuples(rows)
-                   if r in self.edbs.get(name, set())]
-            if old:
-                self.edbs[name] -= set(old)
-                changed.add(name)
+        ins, dels = self._mirror(inserts, deletes)
+        changed = set(ins) | set(dels)
         for name in changed:
             self._refresh_edb(name)
         return changed

@@ -25,13 +25,23 @@ The two primitives
     inside them, so any span can answer "how many sorts / kernel probes
     / all-to-alls did this emit". Exporters:
 
-    * ``to_chrome_trace()`` — Chrome ``trace_event`` JSON (one
-      ``traceEvents`` list of complete ``"X"`` events), loadable in
-      Perfetto / ``chrome://tracing``;
     * ``fixpoint_report()`` — a human-readable per-stratum iteration /
       delta-cardinality table plus per-rule time share;
-    * ``to_dict()`` — a stable dict (``schema_version`` pinned) that
-      ``benchmarks/run.py`` embeds in ``results/bench.json`` rows.
+    * ``to_dict()`` — a stable dict (``schema_version`` pinned).
+
+The profiler's record
+=====================
+
+Every span — with or without an ``Observation`` — also opens a
+``jax.profiler.TraceAnnotation`` named ``flowlog/<name>`` (``PREFIX``),
+whose keyword arguments are the span's host-side attributes (strings
+and numbers; nothing is read back from the device for them). Under
+``jax.profiler.trace`` the engine's spans therefore sit on the host
+plane of the profiler's record, on the same clock as the device's ops,
+so a device-idle gap can be named by the host span it falls in. The
+prefix keeps them apart from the spans of a caller (a benchmark's
+``fixpoint`` or ``apply``). ``python -m repro.observe --trace DIR``
+writes such a record.
 
 What is traced at which layer
 =============================
@@ -41,9 +51,11 @@ What is traced at which layer
   (sharing, verify), under an ambient observation
   (``Observation.activate()``); ``compile_program`` is engine-free, so
   activation is how the CLI / bench attaches the tracer.
-* **engine** (``engine.py``) — ``run`` > ``stratum s<i>`` > ``init`` /
-  ``iteration <k>`` / ``final`` spans. Host mode reads per-iteration
-  delta cardinalities from the loop's *existing* termination reads
+* **engine** (``engine.py``) — ``run`` > ``edb-load`` (host EDB arrays
+  to relations), ``stratum s<i>`` > ``init`` / ``iteration <k>`` /
+  ``final-merge``, then ``export`` (relations back to numpy) spans.
+  Host mode reads per-iteration delta cardinalities from the loop's
+  *existing* termination reads
   (``int(delta.n)`` — a sync the host driver always performs), so
   observe-on adds **no** host syncs inside jitted steps; each iteration
   span carries ``deltas`` (rows per IDB). Device mode hides iterations
@@ -70,48 +82,65 @@ What is traced at which layer
 * **relops / kernels** (``relops.py``, ``backend.py``) — trace-time op
   launch counters ``relops.*`` (join/membership/merge/dedupe/reduce)
   and per-backend kernel-dispatch counters ``kernel.<backend>.*``
-  (probe, segment_reduce, merge_ranks, expand).
+  (probe, segment_reduce, merge_ranks, expand). Each public relop runs
+  under a ``jax.named_scope`` of its own name, so the device ops it
+  emits inside a jitted step carry it in their ``op_name`` metadata
+  (the profiler's ``tf_op``). A relop run eagerly does not: each eager
+  op is a program of its own, whose names start afresh.
 * **sharding** (``shard.py``) — every padded-bucket all-to-all counts
   ``shard.all_to_all.launches`` / ``.slots`` / ``.bytes`` at trace
   time: the padded buffer IS the wire volume (each launch moves the
   whole ``[S, cap, arity]`` buffer regardless of live rows), so the
   byte counter is exact, static, and free. Host-side gathers/scatters
   get real-time spans.
-* **incremental** (``incremental.py``) — ``apply`` > per-stratum
-  maintenance spans tagged with the chosen strategy (``seed-insert`` /
-  ``dred`` / ``recompute``), DRed round counts, and per-update
-  histograms in the observation registry: ``update.latency_s``,
+* **incremental** (``incremental.py``) — ``apply`` covers the whole
+  call and is tiled by ``mirror`` (the host EDB sets), ``refresh-edb``
+  (mirror to stored EDB relation), ``idb-diff`` (an IDB's host rows
+  before and after its stratum, and their set difference),
+  ``maintain-stratum`` (tagged with the chosen strategy ``seed-insert``
+  / ``dred`` / ``recompute``; ``seed-pass``, ``dred-*`` and the
+  stratum spans beneath it) and ``snapshot`` (the view's export).
+  DRed round counts, and per-update histograms in the observation
+  registry: ``update.latency_s`` (the ``apply`` span less its
+  ``mirror`` and ``snapshot``: maintenance without the export),
   ``update.delta_rows`` (IDB-level rows changed per apply).
 
-Zero-overhead contract
-======================
+Overhead contract
+=================
 
-``EngineConfig.observe=None`` (the default) short-circuits every hook
-to an attribute check; no span objects exist, no jax ops are added, and
-fixpoints are byte-identical with the layer on OR off (the observe
-equivalence suite in tests/test_observe.py pins observe-on vs
-observe-off byte-identical outputs and iteration counts across
-jnp/pallas/sharded/incremental configs). The always-on global counters
-are plain Python int increments at *trace* time (amortized across every
-memoized execution), the same cost class as the old
-``relation.COUNTERS``.
+``EngineConfig.observe=None`` (the default) keeps no span objects and
+adds no jax ops, and fixpoints are byte-identical with the layer on OR
+off (the observe equivalence suite in tests/test_observe.py pins
+observe-on vs observe-off byte-identical outputs and iteration counts
+across jnp/pallas/sharded/incremental configs). What a span costs with
+observation off is its ``TraceAnnotation``: about a microsecond on a
+CPU when no profiler runs (the name and attributes are only encoded
+while one does), against a few dozen spans per fixpoint or ``apply``.
+The always-on global counters are plain Python int increments at
+*trace* time (amortized across every memoized execution), the same
+cost class as the old ``relation.COUNTERS``.
 
-This module imports nothing from the engine (stdlib only), so every
-layer — including ``relation.py`` at the bottom and
-``core/optimizer/pipeline.py`` outside the engine — can import it
-without cycles.
+This module imports nothing from the engine (the standard library and
+``jax.profiler`` only), so every layer — including ``relation.py`` at
+the bottom — can import it without cycles; ``core/optimizer/pipeline.py``
+reaches it through ``sys.modules`` so that the front end stays free of
+jax.
 """
 from __future__ import annotations
 
 import contextlib
-import json
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 # stable schema for to_dict() / bench rows; bump on breaking changes to
 # the exported dict/trace structure so downstream report tooling can
 # branch on it
 SCHEMA_VERSION = 1
+
+# name prefix of the engine's spans in the profiler's record
+PREFIX = "flowlog/"
 
 
 # -- metrics registry ---------------------------------------------------------
@@ -268,25 +297,30 @@ def ambient() -> Optional["Observation"]:
     return _ACTIVE[-1] if _ACTIVE else None
 
 
-@contextlib.contextmanager
+def _annotation(name: str, attrs: dict) -> TraceAnnotation:
+    """The profiler annotation of span ``name``: ``flowlog/<name>``,
+    with the attributes that are host strings or numbers."""
+    return TraceAnnotation(PREFIX + name, **{
+        k: v for k, v in attrs.items()
+        if isinstance(v, (str, int, float))})
+
+
 def ambient_span(name: str, **attrs):
-    """Span on the ambient observation, no-op when none is active —
-    the hook engine-free code (the optimizer pipeline) uses."""
-    obs = ambient()
-    if obs is None:
-        yield None
-        return
-    with obs.span(name, **attrs) as sp:
-        yield sp
+    """Span on the ambient observation, or the profiler annotation
+    alone when none is active — the hook engine-free code (the
+    optimizer pipeline) uses."""
+    return span(ambient(), name, **attrs)
 
 
 @contextlib.contextmanager
 def span(obs: Optional["Observation"], name: str, **attrs):
-    """Span helper tolerating ``obs=None`` (the zero-overhead default):
-    engine layers write ``with O.span(self._obs, ...)`` unconditionally
-    and pay one None check when observability is off."""
+    """Span helper tolerating ``obs=None`` (the default): engine layers
+    write ``with O.span(self._obs, ...)`` unconditionally. Without an
+    observation it yields None and opens the profiler annotation
+    alone."""
     if obs is None:
-        yield None
+        with _annotation(name, attrs):
+            yield None
         return
     with obs.span(name, **attrs) as sp:
         yield sp
@@ -326,7 +360,8 @@ class Observation:
             self.roots.append(sp)
         self._stack.append(sp)
         try:
-            yield sp
+            with _annotation(name, attrs):
+                yield sp
         finally:
             self._stack.pop()
             sp.t1 = self._now()
@@ -360,43 +395,6 @@ class Observation:
         return [sp for r in self.roots for sp in r.find(name)]
 
     # -- exporters -----------------------------------------------------------
-    def to_chrome_trace(self) -> dict:
-        """Chrome ``trace_event`` JSON object format: complete ("X")
-        events with microsecond timestamps, loadable in Perfetto /
-        chrome://tracing. Counter deltas and attributes ride in
-        ``args``."""
-        events: list[dict] = []
-
-        def emit(sp: Span, depth: int):
-            args = {str(k): v for k, v in sp.attrs.items()}
-            if sp.counters:
-                args["counters"] = dict(sp.counters)
-            events.append({
-                "name": sp.name,
-                "cat": self.label,
-                "ph": "X",
-                "ts": round(sp.t0 * 1e6, 3),
-                "dur": round(sp.dur * 1e6, 3),
-                "pid": 1,
-                "tid": 1,
-                "args": args,
-            })
-            for c in sp.children:
-                emit(c, depth + 1)
-
-        for r in self.roots:
-            emit(r, 0)
-        return {
-            "traceEvents": events,
-            "displayTimeUnit": "ms",
-            "otherData": {"label": self.label,
-                          "schema_version": SCHEMA_VERSION},
-        }
-
-    def save_chrome_trace(self, path) -> None:
-        with open(path, "w") as f:
-            json.dump(self.to_chrome_trace(), f)
-
     def stratum_summary(self) -> list[dict]:
         """Per-stratum iteration/delta table from the span tree (host
         mode carries per-iteration cardinalities; device mode the
@@ -491,20 +489,3 @@ def _walk(sp: Span):
     for c in sp.children:
         yield from _walk(c)
 
-
-def validate_chrome_trace(trace: dict) -> list[str]:
-    """Schema check for the exported Chrome trace: returns a list of
-    violations (empty = valid). Used by ``make trace-smoke`` and the
-    test suite so the export format cannot bitrot."""
-    errs = []
-    if not isinstance(trace, dict) or "traceEvents" not in trace:
-        return ["missing traceEvents list"]
-    for i, ev in enumerate(trace["traceEvents"]):
-        for field in ("name", "ph", "ts", "pid", "tid"):
-            if field not in ev:
-                errs.append(f"event {i}: missing {field!r}")
-        if ev.get("ph") == "X" and "dur" not in ev:
-            errs.append(f"event {i} ({ev.get('name')}): X without dur")
-        if not isinstance(ev.get("ts", 0), (int, float)):
-            errs.append(f"event {i}: non-numeric ts")
-    return errs

@@ -40,6 +40,7 @@ Correspondence to DD operators (paper Sec. 2.3):
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -52,6 +53,18 @@ from repro.engine.relation import (
     live_mask, pack_key_words, rows_equal_prev,
 )
 from repro.engine.semiring import Semiring, PRESENCE
+
+
+def _scoped(relop):
+    """Run ``relop`` under a ``jax.named_scope`` of its own name, so that
+    every device op it emits inside a jitted program carries the relop
+    in its ``op_name`` metadata (a relop called inside another nests
+    its scope)."""
+    @functools.wraps(relop)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(relop.__name__):
+            return relop(*args, **kwargs)
+    return scoped
 
 
 def _probe_ranks(bk: KernelDispatch, build_words, probe_words):
@@ -89,6 +102,7 @@ def _scatter_compact(data, val, keep, out_cap, val_identity):
     return out, vout, jnp.minimum(n, out_cap), overflow
 
 
+@_scoped
 def dedupe(data: jax.Array, val: Optional[jax.Array], sr: Semiring,
            out_cap: int, assume_sorted: bool = False,
            backend: Optional[KernelDispatch] = None):
@@ -132,6 +146,7 @@ def dedupe(data: jax.Array, val: Optional[jax.Array], sr: Semiring,
     return Relation(d, v, n), ov
 
 
+@_scoped
 def arrange(rel: Relation, key_cols: tuple[int, ...]) -> Relation:
     """Sort a relation so ``key_cols`` form the primary sort order (the
     DD 'arrangement'). Fast path: when ``key_cols`` is already a prefix
@@ -251,6 +266,7 @@ def expand_indices(counts: jax.Array, offsets: jax.Array, out_cap: int):
     return ref.expand_indices_ref(offsets, out_cap)
 
 
+@_scoped
 def join(left: Relation, right: Relation,
          l_keys: tuple[int, ...], r_keys: tuple[int, ...],
          l_out: tuple[int, ...], r_out: tuple[int, ...],
@@ -301,6 +317,7 @@ def join(left: Relation, right: Relation,
     return data, val, valid, total, overflow
 
 
+@_scoped
 def membership(left: Relation, right: Relation,
                l_keys: tuple[int, ...], r_keys: tuple[int, ...],
                right_arranged: bool = False,
@@ -380,6 +397,7 @@ def difference(a: Relation, b: Relation,
     return antijoin(a, b, cols, cols, backend=backend, cache=cache)
 
 
+@_scoped
 def concat_all(rels: Sequence[Relation], sr: Semiring, out_cap: int,
                backend: Optional[KernelDispatch] = None):
     """Multiway union with value combine (ConcatAll, Sec. 4)."""
@@ -439,6 +457,7 @@ def merge_sorted(full: Relation, delta: Relation, sr: Semiring,
                   backend=backend)
 
 
+@_scoped
 def merge(full: Relation, delta: Relation, sr: Semiring, out_cap: int,
           backend: Optional[KernelDispatch] = None,
           incremental: bool = True):
@@ -454,6 +473,7 @@ def merge(full: Relation, delta: Relation, sr: Semiring, out_cap: int,
     return concat_all([full, delta], sr, out_cap, backend=backend)
 
 
+@_scoped
 def merge_with_delta(full: Relation, derived: Relation, sr: Semiring,
                      out_cap: int,
                      backend: Optional[KernelDispatch] = None,
@@ -501,6 +521,7 @@ def merge_with_delta(full: Relation, derived: Relation, sr: Semiring,
     return new_full, Relation(d, v, n), ov1 | ov2
 
 
+@_scoped
 def reduce_groups(rel: Relation, group_cols: tuple[int, ...],
                   aggs: tuple[tuple[str, int], ...], out_cap: int,
                   backend: Optional[KernelDispatch] = None,

@@ -1,20 +1,23 @@
-"""``python -m repro.observe`` — fixpoint profiler / trace exporter.
+"""``python -m repro.observe`` — fixpoint profiler.
 
 Runs a demo Datalog fixpoint with the engine observability layer
-(``repro.engine.observe``) attached, prints the fixpoint report
-(per-stratum iteration/delta table, per-rule time share, metrics), and
-optionally exports a Chrome ``trace_event`` JSON loadable in Perfetto /
-``chrome://tracing``. Wired as ``make trace-smoke``: the CI bench-smoke
-job runs the demo, exports a trace, and validates its schema.
+(``repro.engine.observe``) attached and prints the fixpoint report
+(per-stratum iteration/delta table, per-rule time share, metrics).
+With ``--trace DIR`` the run is recorded by ``jax.profiler`` into DIR:
+the engine's spans (``flowlog/run``, ``flowlog/stratum``,
+``flowlog/iteration``, ...) sit on the host plane of that record and
+the device's ops on theirs, on one clock, for TensorBoard's profiler
+or Perfetto. Wired as ``make trace-smoke``: the CI bench-smoke job runs
+the demo with ``--trace`` and fails unless the record holds the
+engine's spans.
 
 Usage::
 
     python -m repro.observe                          # demo TC, print report
     python -m repro.observe --demo monitor           # 2-stratum demo
-    python -m repro.observe --trace /tmp/trace.json  # export Chrome trace
+    python -m repro.observe --trace /tmp/trace       # + profiler record
     python -m repro.observe --updates 20             # + incremental stream
-    python -m repro.observe --check /tmp/trace.json  # validate a trace file
-    python -m repro.observe --json                   # stable dict (bench form)
+    python -m repro.observe --json                   # stable dict
 
 Demo programs are built in (no dataset files needed); ``--mode device``
 shows the post-hoc summary path (iterations inside ``lax.while_loop``
@@ -68,8 +71,27 @@ def _demo_monitor(size: int):
 DEMOS = {"tc": _demo_tc, "monitor": _demo_monitor}
 
 
-def _run_demo(args) -> int:
-    # engine imports deferred so --check works without touching jax
+def read_spans(logdir) -> list[tuple[str, float, float]]:
+    """The engine's spans in the profiler record under ``logdir``:
+    ``(name, start_ns, end_ns)`` with the ``flowlog/`` prefix taken
+    off, in order of start."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+    from repro.engine.observe import PREFIX
+
+    spans = []
+    for path in sorted(Path(logdir).rglob("*.xplane.pb")):
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(PREFIX):
+                        spans.append((e.name[len(PREFIX):], e.start_ns,
+                                      e.start_ns + e.duration_ns))
+    return sorted(spans, key=lambda s: s[1])
+
+
+def _run_demo(args) -> None:
     from repro.core.optimizer import compile_program
     from repro.engine import EngineConfig, make_engine
     from repro.engine import observe as O
@@ -102,42 +124,26 @@ def _run_demo(args) -> int:
     else:
         print(obs.fixpoint_report())
 
-    if args.trace:
-        from repro.engine.observe import validate_chrome_trace
-        obs.save_chrome_trace(args.trace)
-        trace = obs.to_chrome_trace()
-        errs = validate_chrome_trace(trace)
-        # beyond the schema: the fixpoint lifecycle must actually be in
-        # the trace (host mode exposes per-iteration spans; device mode
-        # only the stratum summary)
-        names = {e["name"] for e in trace["traceEvents"]}
-        need = {"run", "stratum"}
-        if args.mode == "host":
-            need |= {"iteration", "rule"}
-        errs += [f"missing {m!r} span(s)" for m in sorted(need - names)]
-        if errs:
-            print(f"trace INVALID ({len(errs)} violation(s)):")
-            for e in errs:
-                print(f"  {e}")
-            return 1
-        print(f"trace: {args.trace} "
-              f"({len(trace['traceEvents'])} events, schema ok, "
-              f"spans: {', '.join(sorted(need))})")
-    return 0
 
+def _traced_demo(args) -> int:
+    """The demo under ``jax.profiler.trace``; fails unless the record
+    holds the fixpoint's lifecycle (host mode exposes per-iteration
+    spans; device mode only the stratum summary)."""
+    import jax
 
-def _check(path: str) -> int:
-    from repro.engine.observe import validate_chrome_trace
-    with open(path) as f:
-        trace = json.load(f)
-    errs = validate_chrome_trace(trace)
-    if errs:
-        print(f"{path}: INVALID ({len(errs)} violation(s))")
-        for e in errs:
-            print(f"  {e}")
+    with jax.profiler.trace(args.trace):
+        _run_demo(args)
+    names = {name for name, _, _ in read_spans(args.trace)}
+    need = {"run", "stratum"}
+    if args.mode == "host":
+        need |= {"iteration"}
+    missing = sorted(need - names)
+    if missing:
+        print(f"trace INVALID: no {', '.join(missing)} span(s) in the "
+              f"profiler record under {args.trace}")
         return 1
-    print(f"{path}: valid Chrome trace "
-          f"({len(trace['traceEvents'])} events)")
+    print(f"trace: {args.trace} (profiler record; engine spans: "
+          f"{', '.join(sorted(names))})")
     return 0
 
 
@@ -145,7 +151,7 @@ def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.observe",
         description="Fixpoint profiler: run a demo with tracing on, "
-                    "print the report, export/validate Chrome traces")
+                    "print the report, record a profiler trace")
     ap.add_argument("--demo", choices=sorted(DEMOS), default="tc")
     ap.add_argument("--size", type=int, default=64,
                     help="demo graph node count (default 64)")
@@ -156,20 +162,18 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--updates", type=int, default=0,
                     help="also run N incremental update batches and "
                          "report per-update latency")
-    ap.add_argument("--trace", metavar="PATH",
-                    help="export Chrome trace_event JSON here")
+    ap.add_argument("--trace", metavar="DIR",
+                    help="record the run with jax.profiler into DIR")
     ap.add_argument("--json", action="store_true",
-                    help="print the stable dict (bench row form) "
-                         "instead of the report")
-    ap.add_argument("--check", metavar="PATH",
-                    help="validate an existing trace file and exit")
+                    help="print the stable dict instead of the report")
     args = ap.parse_args(argv)
 
-    if args.check:
-        return _check(args.check)
     from repro.compile_cache import enable_compile_cache
     enable_compile_cache()
-    return _run_demo(args)
+    if args.trace:
+        return _traced_demo(args)
+    _run_demo(args)
+    return 0
 
 
 if __name__ == "__main__":

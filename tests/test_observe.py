@@ -1,7 +1,8 @@
 """Observability layer (engine/observe.py): span-tree shape, registry
-scoping, Chrome-trace schema, the relation.COUNTERS shim, and the
-zero-overhead contract — observe-on vs observe-off byte-identical
-fixpoints and iteration counts across jnp/pallas/sharded/incremental
+scoping, the engine's spans in a ``jax.profiler`` record (with the
+observation on and off), the relation.COUNTERS shim, and the overhead
+contract — observe-on vs observe-off byte-identical fixpoints and
+iteration counts across jnp/pallas/sharded/incremental
 configurations."""
 from benchmarks.hostdevices import force_host_device_count
 
@@ -14,13 +15,12 @@ import pytest
 
 import jax
 
-from benchmarks.programs import equivalence_datasets
+from benchmarks.programs import REACH, equivalence_datasets
 from repro.core.optimizer import compile_program
-from repro.engine import (
-    Engine, EngineConfig, Observation, make_engine, validate_chrome_trace,
-)
+from repro.engine import Engine, EngineConfig, Observation, make_engine
 from repro.engine import observe as O
 from repro.engine import relation as RL
+from repro.observe import read_spans
 
 TWO_STRATA = """
 .input edge
@@ -161,26 +161,76 @@ def test_relation_counters_shim_backed_by_registry():
 
 # -- exporters ----------------------------------------------------------------
 
-def test_chrome_trace_schema(rng, tmp_path):
+def _profiled(logdir, fn):
+    """``fn()`` under ``jax.profiler.trace``; its result and the
+    engine's spans in the record."""
+    with jax.profiler.trace(str(logdir)):
+        out = fn()
+    return out, read_spans(logdir)
+
+
+def _inside(spans, outer):
+    """The spans that lie within ``outer`` (itself excluded)."""
+    _, lo, hi = outer
+    return [s for s in spans if s is not outer and lo <= s[1]
+            and s[2] <= hi]
+
+
+def _children(spans, outer):
+    """The spans directly under ``outer``: inside it and inside no
+    other span that is inside it."""
+    within = _inside(spans, outer)
+    return [s for s in within
+            if not any(t is not s and t[1] <= s[1] and s[2] <= t[2]
+                       and (t[1], -t[2]) < (s[1], -s[2])
+                       for t in within)]
+
+
+def test_profiler_trace_holds_the_spans(rng, tmp_path):
+    """With an observation attached, every span of its tree is also in
+    the profiler's record, under the ``flowlog/`` prefix."""
     obs = Observation("t")
-    Engine(compile_program(TWO_STRATA), _cfg(observe=obs)).run(_edbs(rng))
-    trace = obs.to_chrome_trace()
-    assert validate_chrome_trace(trace) == []
-    assert trace["otherData"]["schema_version"] == O.SCHEMA_VERSION
-    names = {e["name"] for e in trace["traceEvents"]}
-    assert {"run", "stratum", "iteration", "rule"} <= names
-    for e in trace["traceEvents"]:
-        assert e["ph"] == "X" and e["dur"] >= 0
+    eng = Engine(compile_program(TWO_STRATA), _cfg(observe=obs))
+    _, spans = _profiled(tmp_path, lambda: eng.run(_edbs(rng)))
+    names = sorted(name for name, _, _ in spans)
+    assert names == sorted(sp.name for r in obs.roots
+                           for sp in O._walk(r))
+    assert {"run", "edb-load", "stratum", "iteration", "rule",
+            "export"} <= set(names)
+    assert all(e >= s for _, s, e in spans)
 
-    # round-trips through JSON on disk and revalidates
-    path = tmp_path / "trace.json"
-    obs.save_chrome_trace(path)
-    assert validate_chrome_trace(json.loads(path.read_text())) == []
 
-    # the validator actually rejects malformed traces
-    assert validate_chrome_trace({}) != []
-    bad = {"traceEvents": [{"ph": "X", "ts": 0, "pid": 1, "tid": 1}]}
-    assert any("name" in e for e in validate_chrome_trace(bad))
+def test_profiler_spans_of_a_reach_fixpoint_without_observation(tmp_path):
+    rng = np.random.default_rng(3)
+    edbs = {"edge": rng.integers(0, 40, size=(80, 2)),
+            "source": np.array([[0]])}
+    eng = Engine(compile_program(REACH), _cfg())
+    (_, stats), spans = _profiled(tmp_path, lambda: eng.run(edbs))
+    [run] = [s for s in spans if s[0] == "run"]
+    assert [s[0] for s in _children(spans, run)] == [
+        "edb-load", "stratum", "export"]
+    [stratum] = [s for s in spans if s[0] == "stratum"]
+    below = [s[0] for s in _children(spans, stratum)]
+    assert below[0] == "init" and below[-1] == "final-merge"
+    assert below.count("iteration") == stats.iterations["s0"]
+
+
+def test_profiler_spans_of_an_insert_apply_without_observation(tmp_path):
+    rng = np.random.default_rng(4)
+    edbs = {"edge": rng.integers(0, 40, size=(80, 2)),
+            "source": np.array([[0]])}
+    inc = make_engine(compile_program(REACH), _cfg(), incremental=True)
+    inc.initialize(edbs)
+    inserts = {"edge": np.array([[0, 41], [41, 42]])}
+    out, spans = _profiled(tmp_path, lambda: inc.apply(inserts=inserts))
+    assert {(42,), (41,)} <= set(map(tuple, out["reach"]))
+    [apply] = [s for s in spans if s[0] == "apply"]
+    assert [s[0] for s in _children(spans, apply)] == [
+        "mirror", "refresh-edb", "idb-diff", "maintain-stratum",
+        "idb-diff", "snapshot"]
+    [maintain] = [s for s in spans if s[0] == "maintain-stratum"]
+    under = {s[0] for s in _inside(spans, maintain)}
+    assert {"seed-pass", "stratum", "iteration"} <= under
 
 
 def test_report_and_dict_exports(rng):

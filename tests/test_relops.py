@@ -1,7 +1,10 @@
 """Unit + property tests for the physical relational operators."""
+import re
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.engine import relops as R
@@ -201,3 +204,27 @@ def test_set_ops_match_python(seed):
     assert set(map(tuple, to_numpy(diff))) == sa - sb
     semi, _ = R.semijoin(ra, rb, (0, 1), (0, 1))
     assert set(map(tuple, to_numpy(semi))) == sa & sb
+
+
+# -- device op names ----------------------------------------------------------
+
+SCOPED = {
+    "join": lambda a, b: R.join(a, b, (1,), (0,), (0, 1), (1,), PRESENCE, 32),
+    "membership": lambda a, b: R.membership(a, b, (1,), (0,)),
+    "arrange": lambda a, b: R.arrange(a, (1,)),
+    "dedupe": lambda a, b: R.dedupe(a.data, None, PRESENCE, 64),
+    "merge": lambda a, b: R.merge(a, b, PRESENCE, 128),
+    "merge_with_delta": lambda a, b: R.merge_with_delta(a, b, PRESENCE, 128),
+    "concat_all": lambda a, b: R.concat_all([a, b], PRESENCE, 128),
+    "reduce_groups": lambda a, b: R.reduce_groups(a, (0,), (("MIN", 1),), 64),
+}
+
+
+@pytest.mark.parametrize("relop", sorted(SCOPED))
+def test_a_relops_ops_carry_its_name(relop):
+    """Each public relop runs under a ``jax.named_scope`` of its name,
+    so its ops' ``op_name`` (a profiler's ``tf_op``) names it."""
+    a = rel_of([[0, 10], [1, 11], [2, 12]])
+    b = rel_of([[10, 5], [12, 7]])
+    text = jax.jit(SCOPED[relop]).lower(a, b).as_text(debug_info=True)
+    assert set(re.findall(r'"jit\(<lambda>\)/(\w+)/', text)) == {relop}
