@@ -503,11 +503,23 @@ class Engine:
 
     def _union_stored(self, rels: list, sr: Semiring, cap: int,
                       context: str = ""):
-        """Stored-form union (combining maintenance seed sets)."""
-        out, ov = R.concat_all(rels, sr, cap, backend=self.backend)
+        """Stored-form union at ``cap`` rows: maintenance seed sets, and
+        an insert-only change into its stored EDB. ``concat_all``'s
+        sort, which on a v5e beats ``merge_sorted``'s rank merge of a
+        2^21-row arrangement with a 2^11-row delta (PERF.md, section 6).
+        Memo-jitted on the operand shapes: a stream of equal-sized
+        batches executes one compiled union."""
+        def union_fn(rs):
+            return R.concat_all(rs, sr, cap, backend=self.backend)
+
+        step = self._memo_jit(
+            ("union_stored", sr.name, cap)
+            + tuple((r.capacity, r.arity) for r in rels),
+            lambda: union_fn)
+        out, ov = step(list(rels))
         if bool(np.asarray(ov).any()):
             raise OverflowError_(self._overflow_msg(
-                "maintenance seed union", context))
+                "maintenance union", context))
         return out
 
     # -- runtime invariant sanitizer (core/analysis/sanitize.py) ---------------

@@ -36,9 +36,10 @@ Sharded-maintenance contract
 What stays **shard-local** (no communication): the seed merge into the
 stored fulls (``merge_with_delta`` per shard block — every block is a
 valid sorted arrangement), the semi-naive frontier differences, the
-DRed candidate removal (``_difference_stored``) and seed-set unions
-(``_union_stored``) — all of these key rows on every stored column, and
-home partitioning co-locates equal rows by full-row hash.
+DRed candidate removal (``_difference_stored``), and the unions of
+seed sets and of an insert-only EDB change into the stored EDB
+(``_union_stored``) — all of these key rows on every stored column,
+and home partitioning co-locates equal rows by full-row hash.
 
 What **repartitions** (all-to-all on the operation key): the joins /
 semijoins / reduces inside a retagged rule pass, exactly as in the
@@ -47,8 +48,9 @@ by full output row before the per-head union (``_merge_head``). The
 DRed candidate/re-derive loop and the ``any_delta`` fixpoint test
 aggregate across shards with a one-scalar psum.
 
-What stays **host-side**: the EDB multiset mirror (``self.edbs``), the
-IR retagging, the DRed candidate frontier sets (small, bounded by the
+What stays **host-side**: the EDB multiset mirror (``self.edbs``), from
+which a stored EDB is rebuilt when a change deletes from it, the IR
+retagging, the DRed candidate frontier sets (small, bounded by the
 over-deletion), and the stratum-pruning closure. Stored fulls stay
 ``ShardedRelation``s across the whole update stream — state is gathered
 to one host only in numpy export (``snapshot``/``to_numpy``) and when
@@ -83,6 +85,7 @@ from repro.engine.engine import EngineConfig, EngineStats
 from repro.engine.relation import (
     Relation, from_numpy, pow2_cap, to_numpy,
 )
+from repro.engine.semiring import PRESENCE
 
 CHANGED = "changed"
 
@@ -294,10 +297,12 @@ class IncrementalEngine:
         for name in changed:
             affected |= self._downstream.get(name, set())
 
-        # refresh EDB relations in env (stored form: the sharded
-        # driver scatters each to its home shards)
+        # bring each changed stored EDB up to its mirror: an insert-only
+        # change merges its new rows into the stored arrangement, one
+        # with deletes rebuilds it from the mirror
         for name in changed:
-            self._refresh_edb(name)
+            self._refresh_edb(
+                name, None if name in real_del else real_ins[name])
 
         # change sets grow as strata update (IDB-level diffs feed
         # downstream)
@@ -416,13 +421,36 @@ class IncrementalEngine:
             return np.array(sorted(rows))
         return np.zeros((0, max(self.compiled.arities[name], 1)))
 
-    def _refresh_edb(self, name: str) -> None:
-        """Mirror -> stored EDB relation in the env (the sharded driver
-        scatters to home shards)."""
-        with O.span(self.engine.cfg.observe, "refresh-edb", relation=name):
-            rows = self._edb_rows(name)
-            self._env[(name, I.FULL)] = self.engine._stored(
-                {name: from_numpy(rows, pow2_cap(len(rows)))})[name]
+    def _refresh_edb(self, name: str,
+                     inserted: Optional[np.ndarray] = None) -> None:
+        """Bring one stored EDB up to its mirror, which already holds
+        the change. ``inserted``, the rows an insert-only change added,
+        merges into the stored arrangement on the device
+        (``_union_stored``); without it, or with no stored EDB yet, the
+        EDB is rebuilt from the mirror (``_rebase_edb``). Both leave
+        the same relation: the mirror's rows at ``pow2_cap`` of their
+        count."""
+        obs = self.engine.cfg.observe
+        key = (name, I.FULL)
+        path = ("merge" if inserted is not None and key in self._env
+                else "rebuild")
+        with O.span(obs, "refresh-edb", relation=name, path=path):
+            O.count(obs, f"incremental.edb_{path}")
+            if path == "rebuild":
+                self._rebase_edb(name)
+                return
+            delta = self._stored_from_rows({name: inserted})[name]
+            self._env[key] = self.engine._union_stored(
+                [self._env[key], delta], PRESENCE,
+                pow2_cap(len(self.edbs[name])), context=f"edb={name}")
+
+    def _rebase_edb(self, name: str) -> None:
+        """Mirror -> stored EDB relation in the env, rebuilt whole (the
+        sharded driver scatters to home shards): re-syncs the store
+        with the mirror after deletes and in ``apply_base``."""
+        rows = self._edb_rows(name)
+        self._env[(name, I.FULL)] = self.engine._stored(
+            {name: from_numpy(rows, pow2_cap(len(rows)))})[name]
 
     # -- recompute rungs (engine/resilience.py degradation ladder) -------------
     def apply_base(self, inserts: Optional[dict] = None,

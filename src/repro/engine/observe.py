@@ -95,11 +95,14 @@ What is traced at which layer
   get real-time spans.
 * **incremental** (``incremental.py``) — ``apply`` covers the whole
   call and is tiled by ``mirror`` (the host EDB sets), ``refresh-edb``
-  (mirror to stored EDB relation), ``idb-diff`` (an IDB's host rows
-  before and after its stratum, and their set difference),
-  ``maintain-stratum`` (tagged with the chosen strategy ``seed-insert``
-  / ``dred`` / ``recompute``; ``seed-pass``, ``dred-*`` and the
-  stratum spans beneath it) and ``snapshot`` (the view's export).
+  (the stored EDB brought up to the mirror; its ``path`` is ``merge``,
+  the inserted rows merged on the device, or ``rebuild``, from the
+  mirror, counted as ``incremental.edb_merge`` / ``.edb_rebuild``),
+  ``idb-diff`` (an IDB's host rows before and after its stratum, and
+  their set difference), ``maintain-stratum`` (tagged with the chosen
+  strategy ``seed-insert`` / ``dred`` / ``recompute``; ``seed-pass``,
+  ``dred-*`` and the stratum spans beneath it) and ``snapshot`` (the
+  view's export).
   DRed round counts, and per-update histograms in the observation
   registry: ``update.latency_s`` (the ``apply`` span less its
   ``mirror`` and ``snapshot``: maintenance without the export),

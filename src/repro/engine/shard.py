@@ -639,14 +639,19 @@ class ShardedEngine(Engine):
     def _union_stored(self, rels: list, sr: Semiring, cap: int,
                       context: str = ""):
         """Shard-local union of home-partitioned relations (duplicates
-        co-locate, so concat + dedupe needs no communication)."""
+        co-locate, so concat + dedupe needs no communication); ``cap``
+        is the per-shard capacity, as ``_scatter_env`` gives it."""
         def union_fn(rels_g):
             out, ov = R.concat_all(_unstack(rels_g), sr, cap,
                                    backend=self.backend)
             return _to_global(out), ov[None]
 
-        out, ov = self._shmap(union_fn)(list(rels))
+        step = self._memo_jit(
+            ("shard_union_stored", sr.name, cap)
+            + tuple((r.capacity, r.arity) for r in rels),
+            lambda: self._shmap(union_fn, jit=False))
+        out, ov = step(list(rels))
         if bool(np.asarray(ov).any()):
             raise OverflowError_(self._overflow_msg(
-                "maintenance seed union", context))
+                "maintenance union", context))
         return out
