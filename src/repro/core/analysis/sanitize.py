@@ -39,17 +39,22 @@ class SanitizerError(AssertionError):
 
 _FNV_OFFSET = np.uint64(14695981039346656037)
 _FNV_PRIME = np.uint64(1099511628211)
+_MIX = (np.uint64(0xFF51AFD7ED558CCD), np.uint64(0xC4CEB9FE1A85EC53))
 
 
 def _host_row_hash(rows: np.ndarray) -> np.ndarray:
     """Host mirror of ``shard._row_hash`` over all columns (uint64
-    FNV-1a; int32 values are widened exactly like jax's astype)."""
+    FNV-1a, then MurmurHash3's finalizer; int32 values are widened
+    exactly like jax's astype)."""
+    shift = np.uint64(33)
     with np.errstate(over="ignore"):
         h = np.full((rows.shape[0],), _FNV_OFFSET, np.uint64)
         for c in range(rows.shape[1]):
             h = (h ^ rows[:, c].astype(np.int64).astype(np.uint64)) \
                 * _FNV_PRIME
-    return h
+        for m in _MIX:
+            h = (h ^ (h >> shift)) * m
+    return h ^ (h >> shift)
 
 
 def check_relation(rel, name: str = "?", where: str = "",

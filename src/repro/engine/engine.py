@@ -141,6 +141,12 @@ class Engine:
         # stratum-boundary counter for the sanitizer's sampling mode
         self._sanitize_count = 0
 
+    def _at_caps(self, key: tuple) -> tuple:
+        """``key`` with the engine's current capacities appended: the
+        key ``_memo_jit`` keeps a compiled step under."""
+        return key + (self._intermediate_cap, self._idb_cap_default,
+                      tuple(sorted(self._idb_caps.items())))
+
     def _memo_jit(self, key: tuple, make):
         """Memoize a jitted stratum function across run()/apply() calls.
 
@@ -160,8 +166,7 @@ class Engine:
             return make()
         obs = self.cfg.observe
         base = key
-        key = key + (self._intermediate_cap, self._idb_cap_default,
-                     tuple(sorted(self._idb_caps.items())))
+        key = self._at_caps(key)
         fn = self._jit_memo.get(key)
         if fn is None:
             if obs is not None:

@@ -89,10 +89,19 @@ What is traced at which layer
   op is a program of its own, whose names start afresh.
 * **sharding** (``shard.py``) — every padded-bucket all-to-all counts
   ``shard.all_to_all.launches`` / ``.slots`` / ``.bytes`` at trace
-  time: the padded buffer IS the wire volume (each launch moves the
-  whole ``[S, cap, arity]`` buffer regardless of live rows), so the
-  byte counter is exact, static, and free. Host-side gathers/scatters
-  get real-time spans.
+  time, so once per compile and not once per fixpoint: the padded
+  buffer IS the wire volume (each launch moves the whole
+  ``[S, cap, arity]`` buffer regardless of live rows), so the byte
+  counter is exact, static, and free. ``repartition_rows`` runs under
+  the ``jax.named_scope`` ``repartition`` (its sort, bucket scatter,
+  all-to-all and dedupe). With an observation attached, the init and
+  host-mode iteration steps also return their repartitions' live send
+  rows, summed per run into the observation counter
+  ``shard.repartition.live_rows``; ``shard.repartition.slots`` adds
+  the steps' static ``S·cap`` per launch on the host, once per
+  execution. Both are over all shards; without an observation the
+  steps are unchanged. The EDB upload to
+  the shards opens ``scatter-edb`` inside ``edb-load``.
 * **incremental** (``incremental.py``) — ``apply`` covers the whole
   call and is tiled by ``mirror`` (the host EDB sets), ``refresh-edb``
   (the stored EDB brought up to the mirror; its ``path`` is ``merge``,

@@ -14,7 +14,8 @@ block) and **every shard block is itself a valid Relation** — rows
 All shard-local relops therefore apply unchanged, including the Pallas
 kernel dispatch (sharded × {jnp, pallas} composes for free).
 
-Rows are placed by an FNV-1a hash of selected columns (``_row_hash``).
+Rows are placed by an FNV-1a hash of selected columns, finalized by
+MurmurHash3's mix (``_row_hash``).
 Materialized relations live on their **home** shard — the hash of the
 *full* row — which makes equal rows co-locate, so the duplicate- and
 value-combining ops of the fixpoint (``merge``, ``merge_with_delta``'s
@@ -67,6 +68,8 @@ Develop/test on CPU with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import NamedTuple, Optional
 
 import jax
@@ -92,6 +95,8 @@ from repro.launch.mesh import SHARD_AXIS, make_shard_mesh
 _SPEC = PartitionSpec(SHARD_AXIS)
 _FNV_OFFSET = np.uint64(14695981039346656037)
 _FNV_PRIME = np.uint64(1099511628211)
+# MurmurHash3's 64-bit finalizer (fmix64)
+_MIX = (np.uint64(0xFF51AFD7ED558CCD), np.uint64(0xC4CEB9FE1A85EC53))
 
 
 class ShardedRelation(NamedTuple):
@@ -146,13 +151,19 @@ def _restack(tree):
 # -- hash partitioning -------------------------------------------------------
 
 def _row_hash(data: jax.Array, cols: tuple[int, ...]) -> jax.Array:
-    """FNV-1a over the selected columns (uint64). Works for any arity —
-    unlike the 62-bit packed row key, so intermediate schemas wider than
-    3 columns still partition fine."""
+    """FNV-1a over the selected columns (uint64), one round a column,
+    then MurmurHash3's finalizer. Works for any arity — unlike the
+    62-bit packed row key, so intermediate schemas wider than 3 columns
+    still partition fine. Without the finalizer a column's bits reach
+    bits 33+ (which pick the shard) only through rare carries: every
+    row of ids below 2**16 then homed on one shard."""
     h = jnp.full((data.shape[0],), _FNV_OFFSET, jnp.uint64)
     for c in cols:
         h = (h ^ data[:, c].astype(jnp.uint64)) * _FNV_PRIME
-    return h
+    shift = jnp.uint64(33)
+    for m in _MIX:
+        h = (h ^ jnp.right_shift(h, shift)) * m
+    return h ^ jnp.right_shift(h, shift)
 
 
 def shard_of(data: jax.Array, cols: tuple[int, ...], live: jax.Array,
@@ -165,6 +176,29 @@ def shard_of(data: jax.Array, cols: tuple[int, ...], live: jax.Array,
     return jnp.where(live, dest, num_shards)
 
 
+# Rows each traced repartition puts into its send buffer, gathered while
+# a step that reports them is traced (``wire_tally``); None otherwise.
+_WIRE: contextvars.ContextVar = contextvars.ContextVar(
+    "shard_wire", default=None)
+
+
+@contextlib.contextmanager
+def wire_tally(on: bool = True):
+    """While a sharded step traces, collect every repartition's live
+    send rows (a traced int64 scalar) and send-buffer slots (a static
+    int); yields the list (None when ``on`` is false, which leaves the
+    traced step as it was)."""
+    if not on:
+        yield None
+        return
+    tally: list = []
+    token = _WIRE.set(tally)
+    try:
+        yield tally
+    finally:
+        _WIRE.reset(token)
+
+
 def repartition_rows(data: jax.Array, val: Optional[jax.Array],
                      live: jax.Array, key_cols: tuple[int, ...],
                      sr: Semiring, out_cap: int, num_shards: int,
@@ -175,43 +209,49 @@ def repartition_rows(data: jax.Array, val: Optional[jax.Array],
     Buckets rows by destination into a padded [S, cap] send buffer,
     swaps buckets with ``jax.lax.all_to_all``, then dedupes the
     received rows — restoring the sorted-arrangement invariant and
-    combining any duplicates that now co-locate. Returns
+    combining any duplicates that now co-locate. Every op it emits
+    (destination sort, bucket scatter, all-to-all, dedupe) runs under
+    the ``jax.named_scope`` ``repartition``. Returns
     (Relation, overflow)."""
-    cap, arity = data.shape
-    if sr.has_value and val is None:
-        val = jnp.ones((cap,), sr.dtype)
-    # trace-time wire-volume accounting: the padded buffer IS the wire
-    # volume — every launch moves the whole [S, cap, arity] send buffer
-    # per shard regardless of live rows, so these per-shard byte/slot
-    # counts are exact and static (int32 = 4 bytes; +1 "column" when a
-    # val plane ships too)
-    trace_count("shard.all_to_all.launches")
-    trace_count("shard.all_to_all.slots", num_shards * cap)
-    planes = arity + (1 if val is not None else 0)
-    trace_count("shard.all_to_all.bytes", num_shards * cap * planes * 4)
-    dest = shard_of(data, key_cols, live, num_shards)
-    order = jnp.argsort(dest)               # stable; dead rows last
-    data = data[order]
-    dst = dest[order]
-    if val is not None:
-        val = val[order]
-    starts = jnp.searchsorted(dst, jnp.arange(num_shards))
-    within = jnp.arange(cap) - starts[jnp.clip(dst, 0, num_shards - 1)]
-    within = jnp.maximum(within, 0)         # dead rows: dst==S drops them
-    send = jnp.full((num_shards, cap, arity), PAD, jnp.int32)
-    send = send.at[dst, within].set(data, mode="drop")
-    recv = jax.lax.all_to_all(send, SHARD_AXIS, split_axis=0,
-                              concat_axis=0)
-    flat = recv.reshape(num_shards * cap, arity)
-    vflat = None
-    if val is not None:
-        identity = sr.identity if sr.has_value else 0
-        sendv = jnp.full((num_shards, cap), identity, val.dtype)
-        sendv = sendv.at[dst, within].set(val, mode="drop")
-        recvv = jax.lax.all_to_all(sendv, SHARD_AXIS, split_axis=0,
-                                   concat_axis=0)
-        vflat = recvv.reshape(num_shards * cap)
-    return R.dedupe(flat, vflat, sr, out_cap, backend=backend)
+    with jax.named_scope("repartition"):
+        cap, arity = data.shape
+        if sr.has_value and val is None:
+            val = jnp.ones((cap,), sr.dtype)
+        # trace-time wire-volume accounting, once per compile (not per
+        # execution): the padded buffer IS the wire volume — every launch
+        # moves the whole [S, cap, arity] send buffer per shard regardless
+        # of live rows, so these per-shard byte/slot counts are exact and
+        # static (int32 = 4 bytes; +1 "column" when a val plane ships too)
+        trace_count("shard.all_to_all.launches")
+        trace_count("shard.all_to_all.slots", num_shards * cap)
+        planes = arity + (1 if val is not None else 0)
+        trace_count("shard.all_to_all.bytes", num_shards * cap * planes * 4)
+        tally = _WIRE.get()
+        if tally is not None:
+            tally.append((live.sum(dtype=jnp.int64), num_shards * cap))
+        dest = shard_of(data, key_cols, live, num_shards)
+        order = jnp.argsort(dest)               # stable; dead rows last
+        data = data[order]
+        dst = dest[order]
+        if val is not None:
+            val = val[order]
+        starts = jnp.searchsorted(dst, jnp.arange(num_shards))
+        within = jnp.arange(cap) - starts[jnp.clip(dst, 0, num_shards - 1)]
+        within = jnp.maximum(within, 0)         # dead rows: dst==S drops them
+        send = jnp.full((num_shards, cap, arity), PAD, jnp.int32)
+        send = send.at[dst, within].set(data, mode="drop")
+        recv = jax.lax.all_to_all(send, SHARD_AXIS, split_axis=0,
+                                  concat_axis=0)
+        flat = recv.reshape(num_shards * cap, arity)
+        vflat = None
+        if val is not None:
+            identity = sr.identity if sr.has_value else 0
+            sendv = jnp.full((num_shards, cap), identity, val.dtype)
+            sendv = sendv.at[dst, within].set(val, mode="drop")
+            recvv = jax.lax.all_to_all(sendv, SHARD_AXIS, split_axis=0,
+                                       concat_axis=0)
+            vflat = recvv.reshape(num_shards * cap)
+        return R.dedupe(flat, vflat, sr, out_cap, backend=backend)
 
 
 def repartition(rel: Relation, key_cols: tuple[int, ...], sr: Semiring,
@@ -310,6 +350,9 @@ class ShardedEngine(Engine):
         super().__init__(compiled, config)
         self.num_shards = max(int(self.cfg.shards or 1), 1)
         self.mesh = self.cfg.shard_mesh or make_shard_mesh(self.num_shards)
+        # send-buffer slots of one execution of an observed step, over
+        # all shards, by the step's memo key at its capacities
+        self._wire_slots: dict = {}
         if self.mesh.axis_names != (SHARD_AXIS,):
             raise ValueError(
                 f"shard mesh must have the single axis {SHARD_AXIS!r}, "
@@ -328,7 +371,10 @@ class ShardedEngine(Engine):
     def _scatter_env(self, rels: dict) -> dict:
         """Host-built (replicated) Relations -> home-partitioned
         ShardedRelations: each shard keeps the rows whose full-row hash
-        lands on it. Stable compaction preserves sortedness."""
+        lands on it. Stable compaction preserves sortedness. The
+        replication to every shard and the compaction run under the span
+        ``scatter-edb`` (inside ``edb-load`` for the EDBs, inside
+        ``init`` for an IDB's ground facts)."""
         if not rels:
             return {}
         O.count(self.cfg.observe, "shard.scatter_env", len(rels))
@@ -351,7 +397,16 @@ class ShardedEngine(Engine):
                     d, v if rel.val is not None else None, n)
             return _restack(out)
 
-        return self._shmap(scatter, in_specs=PartitionSpec())(rels)
+        # one program per set of relation shapes, so a run that scatters
+        # what an earlier run did compiles nothing
+        step = self._memo_jit(
+            ("shard_scatter",) + tuple(
+                (k, r.capacity, r.arity, r.val is not None,
+                 identities[k].name) for k, r in rels.items()),
+            lambda: self._shmap(scatter, in_specs=PartitionSpec(),
+                                jit=False))
+        with O.span(self.cfg.observe, "scatter-edb", relations=len(rels)):
+            return step(rels)
 
     def _edb_env(self, edbs, edb_caps) -> dict:
         return self._scatter_env(super()._edb_env(edbs, edb_caps))
@@ -398,6 +453,11 @@ class ShardedEngine(Engine):
         ev = ShardedEvaluator(lcfg, self.num_shards)
         monoid_names = set(self.monoid)
         idbs = sorted(sp.idbs)
+        # the init and host-mode iteration steps also return their
+        # repartitions' live send rows when observation is on
+        wired = obs is not None
+        init_key = ("shard_init", sp.index, wired)
+        iter_key = ("shard_iter", sp.index, wired)
 
         nonrec = [p for p in sp.plans if p.variant == -1]
         rec = [p for p in sp.plans if p.variant >= 0]
@@ -431,18 +491,20 @@ class ShardedEngine(Engine):
         else:
             def init_fn(base_g, init_g):
                 base, init = _unstack(base_g), _unstack(init_g)
-                state, ovf = self._stratum_init(
-                    base, init, nonrec, idbs, ev, monoid_names)
-                return _restack(state), ovf[None]
+                with wire_tally(wired) as tally:
+                    state, ovf = self._stratum_init(
+                        base, init, nonrec, idbs, ev, monoid_names)
+                return self._with_wire(
+                    init_key, (_restack(state), ovf[None]), tally)
 
             with O.span(obs, "init", nonrec_rules=len(nonrec)):
                 init_rels = self._scatter_env(
                     {name: self._ground_relation(sp, name)
                      for name in idbs})
                 init_step = self._memo_jit(
-                    ("shard_init", sp.index),
-                    lambda: self._shmap(init_fn, jit=False))
-                state, ovf = init_step(dict(env_rels), init_rels)
+                    init_key, lambda: self._shmap(init_fn, jit=False))
+                state, ovf = self._read_wire(
+                    init_key, init_step(dict(env_rels), init_rels))
                 ovf = bool(np.asarray(ovf).any())
         if ovf:
             raise OverflowError_(f"overflow during init of {stratum_key}")
@@ -496,11 +558,13 @@ class ShardedEngine(Engine):
         else:
             def step_fn(state_g, base_g):
                 state, base = _unstack(state_g), _unstack(base_g)
-                ns, ovf = self._stratum_iter(
-                    state, base, rec, idbs, ev, monoid_names)
-                return _restack(ns), ovf[None]
+                with wire_tally(wired) as tally:
+                    ns, ovf = self._stratum_iter(
+                        state, base, rec, idbs, ev, monoid_names)
+                return self._with_wire(
+                    iter_key, (_restack(ns), ovf[None]), tally)
 
-            step = self._memo_jit(("shard_iter", sp.index),
+            step = self._memo_jit(iter_key,
                                   lambda: self._shmap(step_fn, jit=False))
             # per-iteration deltas ride the loop's existing per-shard
             # count reads (the [S] sum) — no host syncs added
@@ -512,7 +576,8 @@ class ShardedEngine(Engine):
                 with O.span(obs, "iteration", index=stratum_iters,
                             delta_rows=delta_total,
                             deltas=dict(sizes) if obs else None):
-                    state, ovf = step(state, dict(env_rels))
+                    state, ovf = self._read_wire(
+                        iter_key, step(state, dict(env_rels)))
                     ovf = bool(np.asarray(ovf).any())
                     sizes = {n: int(np.asarray(state[n][1].n).sum())
                              for n in idbs}
@@ -556,6 +621,32 @@ class ShardedEngine(Engine):
             st_span.attrs["iterations"] = stratum_iters
         self._sanitize_env(full_env, f"stratum {stratum_key} boundary")
         return full_env
+
+    # -- wire counts of an observed run ---------------------------------------
+    def _with_wire(self, key: tuple, out: tuple, tally) -> tuple:
+        """A step's outputs, with its shard's live send rows appended
+        when ``tally`` collected them. The slots are static: every shard
+        runs the same buffers, so their total over the shards is kept on
+        the host, by the step's memo key at its capacities."""
+        if tally is None:
+            return out
+        self._wire_slots[self._at_caps(key)] = self.num_shards * sum(
+            s for _, s in tally)
+        live = sum((n for n, _ in tally), jnp.zeros((), jnp.int64))
+        return out + (live[None],)
+
+    def _read_wire(self, key: tuple, out: tuple) -> tuple:
+        """Strip the live send rows off an observed step's outputs and
+        add them, summed over the shards, to the observation's
+        ``shard.repartition.live_rows``, and the step's slots to
+        ``.slots``."""
+        if self.cfg.observe is None:
+            return out
+        O.count(self.cfg.observe, "shard.repartition.live_rows",
+                int(np.asarray(out[-1]).sum()))
+        O.count(self.cfg.observe, "shard.repartition.slots",
+                self._wire_slots[self._at_caps(key)])
+        return out[:-1]
 
     # -- head merge: re-home derived rows before combining --------------------
     def _merge_head(self, rels: list, sr: Semiring, cap: int):

@@ -135,6 +135,27 @@ def test_sharded_skewed_keys():
     _assert_equivalent(TC, edbs, _cfg(shards=8))
 
 
+@pytest.mark.parametrize("cols", [(0,), (0, 1)])
+def test_home_hash_spreads_small_ids(cols):
+    """Vertex ids below 2**16 spread evenly over 4 shards, by one column
+    (a join key, an arity-1 IDB) or by the full row, and the
+    sanitizer's host mirror homes every row where the engine does.
+    (Before the hash's finalizer every such row homed on one shard.)"""
+    import jax.numpy as jnp
+    from repro.core.analysis.sanitize import _host_row_hash
+    from repro.engine.shard import shard_of
+
+    rows = np.random.default_rng(3).integers(0, 1 << 16, (1 << 16, 2))
+    dest = np.asarray(shard_of(jnp.asarray(rows), cols,
+                               jnp.ones(len(rows), bool), 4))
+    counts = np.bincount(dest, minlength=4)
+    assert counts.shape == (4,)
+    assert np.all(np.abs(counts - len(rows) / 4) < 0.03 * len(rows) / 4)
+    host = (_host_row_hash(rows[:, list(cols)]) >> np.uint64(33)) \
+        % np.uint64(4)
+    np.testing.assert_array_equal(host, dest)
+
+
 def test_sharded_empty_shards():
     """Fewer live rows than shards: most shards hold nothing at every
     iteration and the fixpoint still terminates identically."""
