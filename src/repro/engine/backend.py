@@ -90,11 +90,16 @@ implementation of ``expand``. See ROADMAP "Open items".
 """
 from __future__ import annotations
 
+import functools
+import threading
+
 import jax
 import jax.numpy as jnp
 
+from repro.engine import observe as O
 from repro.engine.observe import trace_count
 from repro.kernels import ops
+from repro.kernels.merge_probe import band_blocks
 
 
 class KernelDispatch:
@@ -173,6 +178,11 @@ class KernelDispatch:
         return (jnp.arange(m, dtype=jnp.int32) + lo_a,
                 jnp.arange(n, dtype=jnp.int32) + hi_b)
 
+    def observed(self, obs) -> "KernelDispatch":
+        """This dispatch with the observation ``obs`` attached (None:
+        this dispatch as it is). Only the Pallas probe reports to it."""
+        return self
+
     def expand(self, offsets: jax.Array, out_cap: int):
         """The join's bounded expand: output slot j -> (input row,
         within-group index, valid, total). Routed through the seam so a
@@ -229,18 +239,46 @@ class PallasDispatch(KernelDispatch):
 
     needs_sorted_probe = True
 
-    def __init__(self, interpret: bool):
+    def __init__(self, interpret: bool, observe=None):
         self.interpret = interpret
         self.name = "pallas-interpret" if interpret else "pallas"
         self._mode = "interpret" if interpret else "pallas"
+        self.observe = observe
+
+    def observed(self, obs) -> "PallasDispatch":
+        if obs is None or obs is self.observe:
+            return self
+        return PallasDispatch(self.interpret, obs)
+
+    def _count_blocks(self, build, probe) -> None:
+        """With an observation attached, count a probe's block pairs
+        each time the traced program runs: the band pairs the kernel
+        compares (``kernels.probe.compared_blocks``) and the ``nP * nB``
+        pairs of the whole block grid (``kernels.probe.grid_blocks``).
+        The count reaches the host through a callback, which programs
+        traced without an observation do not compile."""
+        if self.observe is None:
+            return
+        compared, grid = band_blocks(build, probe)
+        jax.debug.callback(functools.partial(self._tally, grid), compared)
+
+    def _tally(self, grid: int, compared) -> None:
+        """The block counter's host side. The shards of one program call
+        back at once, so the counts are added under a lock."""
+        with _TALLY_LOCK:
+            O.count(self.observe, "kernels.probe.compared_blocks",
+                    int(compared))
+            O.count(self.observe, "kernels.probe.grid_blocks", grid)
 
     def probe(self, build_keys, probe_keys):
         self._count("probe")
+        self._count_blocks(build_keys, probe_keys)
         return ops.merge_probe_counts(build_keys, probe_keys,
                                       backend=self._mode)
 
     def probe_multi(self, build_words, probe_words):
         self._count("probe_multi")
+        self._count_blocks(build_words, probe_words)
         return ops.merge_probe_multi(build_words, probe_words,
                                      backend=self._mode)
 
@@ -248,10 +286,14 @@ class PallasDispatch(KernelDispatch):
         # both rank passes through the blocked merge-path kernel (both
         # sequences are sorted arrangements — the kernel's contract)
         self._count("merge_ranks")
+        self._count_blocks(b_keys, a_keys)
+        self._count_blocks(a_keys, b_keys)
         return ops.merge_ranks(a_keys, b_keys, backend=self._mode)
 
     def merge_ranks_multi(self, a_words, b_words):
         self._count("merge_ranks_multi")
+        self._count_blocks(b_words, a_words)
+        self._count_blocks(a_words, b_words)
         return ops.merge_ranks_multi(a_words, b_words,
                                      backend=self._mode)
 
@@ -266,6 +308,7 @@ class PallasDispatch(KernelDispatch):
 
 
 JNP = JnpDispatch()
+_TALLY_LOCK = threading.Lock()
 
 _CHOICES = ("auto", "pallas", "pallas-interpret", "jnp")
 

@@ -118,7 +118,7 @@ class Engine:
         self.compiled = compiled
         self.cfg = config or EngineConfig()
         self.backend: KernelDispatch = resolve_backend(
-            self.cfg.kernel_backend)
+            self.cfg.kernel_backend).observed(self.cfg.observe)
         self.monoid: dict[str, tuple[Semiring, int]] = {}
         for name, (func, vpos) in compiled.monoid_idbs.items():
             self.monoid[name] = (monoid_for(func), vpos)

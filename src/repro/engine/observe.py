@@ -82,7 +82,13 @@ What is traced at which layer
 * **relops / kernels** (``relops.py``, ``backend.py``) — trace-time op
   launch counters ``relops.*`` (join/membership/merge/dedupe/reduce)
   and per-backend kernel-dispatch counters ``kernel.<backend>.*``
-  (probe, segment_reduce, merge_ranks, expand). Each public relop runs
+  (probe, segment_reduce, merge_ranks, expand). With an observation
+  attached, every Pallas probe also counts, each time its program
+  runs, the block pairs its kernel compares
+  (``kernels.probe.compared_blocks``) and the pairs of the whole
+  probe × build block grid (``kernels.probe.grid_blocks``), through a
+  host callback that programs traced without an observation do not
+  compile (``PallasDispatch.observed``). Each public relop runs
   under a ``jax.named_scope`` of its own name, so the device ops it
   emits inside a jitted step carry it in their ``op_name`` metadata
   (the profiler's ``tf_op``). A relop run eagerly does not: each eager

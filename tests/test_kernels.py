@@ -73,6 +73,177 @@ def test_merge_probe_duplicates():
     assert (hi - lo).tolist() == [0, 3, 0, 2, 1, 0]
 
 
+KEY_PAD = np.iinfo(np.int64).max
+PROBE_BLOCK, BUILD_BLOCK = 128, 256
+
+
+def _padded_keys(rng, rows, live, words):
+    """``rows`` sorted keys whose last ``rows - live`` are KEY_PAD: [rows]
+    int64 keys (``words`` 0) or [rows, words] int64 words, KEY_PAD in
+    every word of a padding row. Few distinct values, so keys repeat."""
+    if not words:
+        keys = np.sort(rng.integers(0, 400, size=live))
+        return np.concatenate([keys, np.full(rows - live, KEY_PAD)])
+    keys = rng.integers(0, 20, size=(live, words))
+    keys = keys[np.lexsort(keys.T[::-1])]
+    return np.concatenate([keys, np.full((rows - live, words), KEY_PAD)])
+
+
+def _ranks_ref(build, probe):
+    if build.ndim == 1:
+        return ref.merge_probe_ref(jnp.asarray(build), jnp.asarray(probe))
+    return ref.merge_probe_multi_ref(jnp.asarray(build), jnp.asarray(probe))
+
+
+def _probe(build, probe):
+    if build.ndim == 1:
+        return ops.merge_probe_counts(
+            jnp.asarray(build), jnp.asarray(probe), backend="interpret",
+            probe_block=PROBE_BLOCK, build_block=BUILD_BLOCK)
+    return ops.merge_probe_multi(
+        jnp.asarray(build), jnp.asarray(probe), backend="interpret",
+        probe_block=PROBE_BLOCK, build_block=BUILD_BLOCK)
+
+
+def _is_pad(keys):
+    pad = keys == KEY_PAD
+    return pad if keys.ndim == 1 else pad.all(axis=1)
+
+
+# (probe rows, live probe rows): whole KEY_PAD blocks, a partly padded
+# last block, no padding. The build (1000 rows, 700 live) has a KEY_PAD
+# tail and is padded further to 4 blocks of 256.
+@pytest.mark.parametrize("words", [0, 1, 3])
+@pytest.mark.parametrize("n,n_live", [(1024, 200), (1000, 950), (640, 640)],
+                         ids=["pad-blocks", "pad-tail", "no-pad"])
+def test_merge_probe_padding_ranks_closed_form(rng, words, n, n_live):
+    m, m_live = 1000, 700
+    build = _padded_keys(rng, m, m_live, words)
+    probe = _padded_keys(rng, n, n_live, words)
+    lo, hi = map(np.asarray, _probe(build, probe))
+    rlo, rhi = map(np.asarray, _ranks_ref(build, probe))
+    live = ~_is_pad(probe)
+    np.testing.assert_array_equal(lo[live], rlo[live])
+    np.testing.assert_array_equal(hi[live], rhi[live])
+    # padding rows: lo = the build rows below KEY_PAD, hi = the build as
+    # the kernel pads it to whole blocks
+    m_pad = -(-m // BUILD_BLOCK) * BUILD_BLOCK
+    assert (lo[~live] == m_live).all()
+    assert (hi[~live] == m_pad).all()
+
+
+@pytest.mark.parametrize("words", [0, 2])
+def test_merge_ranks_padded_tails(rng, words):
+    """Merge positions of two arrangements with KEY_PAD tails: the live
+    rows land where ``ref.merge_ranks_ref`` puts them, and no padding
+    row lands on a live row's position."""
+    m, m_live, n, n_live = 900, 600, 700, 300
+    a = _padded_keys(rng, m, m_live, words)
+    b = _padded_keys(rng, n, n_live, words)
+    if words:
+        pos_a, pos_b = ops.merge_ranks_multi(
+            jnp.asarray(a), jnp.asarray(b), backend="interpret",
+            probe_block=PROBE_BLOCK, build_block=BUILD_BLOCK)
+        ra, rb = ref.merge_ranks_multi_ref(jnp.asarray(a), jnp.asarray(b))
+    else:
+        pos_a, pos_b = ops.merge_ranks(
+            jnp.asarray(a), jnp.asarray(b), backend="interpret",
+            probe_block=PROBE_BLOCK, build_block=BUILD_BLOCK)
+        ra, rb = ref.merge_ranks_ref(jnp.asarray(a), jnp.asarray(b))
+    pos_a, pos_b, ra, rb = map(np.asarray, (pos_a, pos_b, ra, rb))
+    live_a, live_b = ~_is_pad(a), ~_is_pad(b)
+    np.testing.assert_array_equal(pos_a[live_a], ra[live_a])
+    np.testing.assert_array_equal(pos_b[live_b], rb[live_b])
+    live_pos = np.concatenate([pos_a[live_a], pos_b[live_b]])
+    assert np.array_equal(np.sort(live_pos), np.arange(m_live + n_live))
+    dead_pos = np.concatenate([pos_a[~live_a], pos_b[~live_b]])
+    assert not np.isin(dead_pos, live_pos).any()
+
+
+def test_probe_band_walks_live_blocks_only(rng):
+    """A probe 90% KEY_PAD into a build 90% KEY_PAD compares at most
+    (live probe blocks + live build blocks) block pairs, and an observed
+    dispatch counts exactly those and the whole grid."""
+    from repro.engine import observe as O
+    from repro.engine.backend import PallasDispatch
+    from repro.kernels import merge_probe as MP
+
+    block = 1024                    # the dispatch's block sizes
+    m = n = 20 * block
+    live = m // 10
+    draw = rng.choice(1 << 40, size=2 * live, replace=False)
+    build = np.concatenate([np.sort(draw[:live]), np.full(m - live, KEY_PAD)])
+    probe = np.concatenate([np.sort(draw[live:]), np.full(n - live, KEY_PAD)])
+    r_lo, r_hi = MP._block_bounds(jnp.asarray(build), jnp.asarray(probe),
+                                  block, block)
+    compared = int(np.sum(np.asarray(r_hi) - np.asarray(r_lo)))
+    live_blocks = 2 * (-(-live // block))
+    assert 0 < compared <= live_blocks
+    grid = (n // block) * (m // block)
+
+    obs = O.Observation()
+    lo, hi = PallasDispatch(interpret=True, observe=obs).probe(
+        jnp.asarray(build), jnp.asarray(probe))
+    jax.effects_barrier()
+    assert obs.registry.get("kernels.probe.compared_blocks") == compared
+    assert obs.registry.get("kernels.probe.grid_blocks") == grid
+    rlo, rhi = ref.merge_probe_ref(jnp.asarray(build), jnp.asarray(probe))
+    np.testing.assert_array_equal(np.asarray(lo)[:live],
+                                  np.asarray(rlo)[:live])
+    np.testing.assert_array_equal(np.asarray(hi)[:live],
+                                  np.asarray(rhi)[:live])
+
+
+def test_probe_counter_tally_loses_no_update():
+    """Shards call the counter back from threads of their own: many
+    threads adding at once, with thread switches forced often, lose no
+    count."""
+    import sys
+    import threading
+
+    from repro.engine import observe as O
+    from repro.engine.backend import PallasDispatch
+
+    obs = O.Observation()
+    dispatch = PallasDispatch(interpret=True, observe=obs)
+    threads, adds = 32, 2000
+
+    def work():
+        for _ in range(adds):
+            dispatch._tally(3, np.int32(1))
+
+    before = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        pool = [threading.Thread(target=work) for _ in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(before)
+    assert not any(t.is_alive() for t in pool)
+    assert obs.registry.get("kernels.probe.compared_blocks") == threads * adds
+    assert obs.registry.get("kernels.probe.grid_blocks") == 3 * threads * adds
+
+
+def test_probe_counter_compiles_only_when_observed():
+    """The block counter's host callback is in an observed dispatch's
+    program and in no other."""
+    from repro.engine import observe as O
+    from repro.engine.backend import PallasDispatch
+
+    keys = jnp.arange(2048, dtype=jnp.int64)
+    plain = PallasDispatch(interpret=True)
+    assert plain.observed(None) is plain
+
+    def hlo(dispatch):
+        return jax.jit(dispatch.probe).lower(keys, keys).as_text()
+
+    assert "callback" not in hlo(plain)
+    assert "callback" in hlo(plain.observed(O.Observation()))
+
+
 # -- fm_interaction -----------------------------------------------------------
 
 @pytest.mark.parametrize("b,f,k", [(32, 39, 10), (1000, 39, 10),

@@ -82,6 +82,20 @@ def _merge_ranks_multi(one):
             (_keys((ROWS, 2), one), _keys((ROWS // 4, 2), one)))
 
 
+def _reach_probe(probe_rows, build_rows, words):
+    """The probe at the engine's Reach shapes: the 2**21-row edge block
+    probed into (or by) the 2**17-row ``reach`` and its delta."""
+    def case(one):
+        if not words:
+            return (lambda b, p: ops.merge_probe_counts(
+                        b, p, backend="pallas"),
+                    (_keys((build_rows,), one), _keys((probe_rows,), one)))
+        return (lambda b, p: ops.merge_probe_multi(b, p, backend="pallas"),
+                (_keys((build_rows, words), one),
+                 _keys((probe_rows, words), one)))
+    return case
+
+
 def _segment(op, num_segments):
     def case(one):
         return (lambda v, s: ops.segment_reduce(v, s, num_segments, op,
@@ -95,6 +109,9 @@ CASES = {
     "probe_multi": _probe_multi,
     "merge_ranks": _merge_ranks,
     "merge_ranks_multi": _merge_ranks_multi,
+    **{f"reach_probe_{p}_{b}_{kind}": _reach_probe(1 << p, 1 << b, words)
+       for p, b in ((21, 17), (17, 17))
+       for kind, words in (("single", 0), ("multi", 2))},
     **{f"segment_{op}_{path}": _segment(op, segs)
        for op in ("sum", "min", "max")
        for path, segs in (("resident", RESIDENT_MAX_SEGMENTS),
